@@ -35,8 +35,7 @@ def main():
     idx, meta = build_index(corpus)
     rng = np.random.default_rng(0)
 
-    on_tpu = jax.default_backend() == "tpu"
-    mode = "compiled" if on_tpu else "interpret"
+    mode = "interpret" if ops.resolve_interpret() else "compiled"
     for n_terms in (1, 2, 3):
         q = [
             (list(rng.integers(0, 64, size=n_terms)), None)
@@ -49,7 +48,7 @@ def main():
             print(f"backends,topk_t{n_terms}_w{window}_jnp,"
                   f"{dt/len(q)*1e6:.1f},per_query_us")
             dt = _timed(query_topk, idx, qb, k=10, window=window,
-                        backend="pallas", interpret=not on_tpu, reps=2)
+                        backend="pallas", reps=2)
             print(f"backends,topk_t{n_terms}_w{window}_pallas,"
                   f"{dt/len(q)*1e6:.1f},per_query_us_{mode}")
 

@@ -32,8 +32,6 @@ def _timed(fn, *args, reps=3, **kw):
 
 
 def main(backend: str = "jnp"):
-    on_tpu = jax.default_backend() == "tpu"
-    interpret = None if backend == "jnp" else (not on_tpu)
     corpus = generate_corpus(
         CorpusConfig(n_docs=20_000, vocab_size=3_000, mean_doc_len=60,
                      n_sites=100, seed=0)
@@ -54,7 +52,7 @@ def main(backend: str = "jnp"):
         for rep in range(r):
             for s, (idx, _) in enumerate(meta_idx):
                 dt = _timed(query_topk, idx, qb, k=k, window=2048,
-                            backend=backend, interpret=interpret, reps=1)
+                            backend=backend, reps=1)
                 per_query_shard[:, rep * 5 + s] = dt / len(ss)
         sojourns.append(per_query_shard)
         us = per_query_shard.mean() * 1e6
@@ -71,7 +69,7 @@ def main(backend: str = "jnp"):
     for strat in ("embed", "gather", "site_term"):
         qb = make_query_batch(q, t_max=4, meta=meta_full, strategy=strat)
         dt = _timed(query_topk, idx_full, qb, k=10, window=2048,
-                    attr_strategy=strat, backend=backend, interpret=interpret)
+                    attr_strategy=strat, backend=backend)
         print(f"engine,limited_search_{strat},{dt/len(q)*1e6:.1f},per_query_us")
 
     # posting skipping effectiveness.  Tile skipping pays when the driver

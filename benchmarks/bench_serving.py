@@ -51,6 +51,7 @@ from repro.core.index import build_sharded_index, pack_flat_postings
 from repro.core.parallel import set_mesh_slices
 from repro.core.perfmodel import estimation_error
 from repro.data.corpus import CorpusConfig, generate_corpus
+from repro.kernels import ops
 from repro.obs import (
     MetricsRegistry,
     ModelResidualMonitor,
@@ -80,10 +81,8 @@ def _mean_response(tickets) -> float:
 
 
 def main(backend: str = "jnp", smoke: bool = False, sets=None):
-    on_tpu = jax.default_backend() == "tpu"
-    interpret = None if backend == "jnp" else (not on_tpu)
-    mode = "compiled" if on_tpu else (
-        "interpret" if backend == "pallas" else "jnp"
+    mode = "jnp" if backend == "jnp" else (
+        "interpret" if ops.resolve_interpret() else "compiled"
     )
     n_docs = 600 if smoke else 8000
     vocab = 200 if smoke else 1200
@@ -118,7 +117,7 @@ def main(backend: str = "jnp", smoke: bool = False, sets=None):
     # --- 1. closed-loop calibration from the live engine -------------------
     cal = calibrate_from_engine(
         sharded, meta, mesh, ns=ns, k_values=k_values, window=window,
-        q=batch_size, reps=reps, backend=backend, interpret=interpret,
+        q=batch_size, reps=reps, backend=backend,
     )
     for k in k_values:
         print(f"serving,st_slave_k{k},{cal.st_slave[k]*1e6:.2f},us_{mode}")
@@ -131,7 +130,7 @@ def main(backend: str = "jnp", smoke: bool = False, sets=None):
     def make_service(cache_size: int, registry=None) -> SearchService:
         svc = SearchService(
             sharded, meta, mesh, ns=ns, k=10, window=window, t_max=2,
-            t_max_buckets=(2,), backend=backend, interpret=interpret,
+            t_max_buckets=(2,), backend=backend,
             batch_size=batch_size, cache_size=cache_size,
             registry=registry,
         )
@@ -226,7 +225,7 @@ def main(backend: str = "jnp", smoke: bool = False, sets=None):
         slices = set_mesh_slices(S, ns)
         svc = SearchService(
             sharded, meta, slices[0], ns=ns, k=10, window=window, t_max=2,
-            t_max_buckets=(2,), backend=backend, interpret=interpret,
+            t_max_buckets=(2,), backend=backend,
             batch_size=batch_size, cache_size=0,
             n_sets=S, set_meshes=slices,
         )

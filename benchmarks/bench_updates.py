@@ -53,6 +53,7 @@ from repro.data.corpus import (
 )
 from repro.indexing import DeltaWriter, compact
 from repro.indexing.delta import local_delta
+from repro.kernels import ops
 from repro.obs import MetricsRegistry, set_registry
 
 
@@ -72,11 +73,11 @@ def _timed(fn, *args, reps=5, **kw):
     return _stats(samples)
 
 
-def _query_latency(idx, delta, qb, *, window, backend, interpret, reps=5,
+def _query_latency(idx, delta, qb, *, window, backend, reps=5,
                    codec="raw"):
     return _timed(
         query_topk, idx, qb, delta=delta, k=10, window=window,
-        backend=backend, interpret=interpret, codec=codec, reps=reps,
+        backend=backend, codec=codec, reps=reps,
     )
 
 
@@ -88,7 +89,7 @@ def _stats(samples):
     )
 
 
-def _query_latency_pair(idx, delta, qb, *, window, interpret, reps=9,
+def _query_latency_pair(idx, delta, qb, *, window, reps=9,
                         variants=(("pallas", "raw"), ("pallas_staged", "raw"))):
     """Two query variants timed with *interleaved* reps, plus the median
     per-rep ``first/second`` ratio.
@@ -104,7 +105,7 @@ def _query_latency_pair(idx, delta, qb, *, window, interpret, reps=9,
     def run(backend, codec):
         return query_topk(
             idx, qb, delta=delta, k=10, window=window,
-            backend=backend, interpret=interpret, codec=codec,
+            backend=backend, codec=codec,
         )
 
     for v in variants:                            # compile
@@ -121,7 +122,7 @@ def _query_latency_pair(idx, delta, qb, *, window, interpret, reps=9,
     return _stats(first), _stats(second), ratio
 
 
-def _compact_pair(idx, delta, qb, *, window, interpret, live_q=None, reps=9):
+def _compact_pair(idx, delta, qb, *, window, live_q=None, reps=9):
     """Compacted work-list grid vs the dense streamed grid, interleaved
     reps (same statistic discipline as :func:`_query_latency_pair`).
 
@@ -133,12 +134,12 @@ def _compact_pair(idx, delta, qb, *, window, interpret, live_q=None, reps=9):
         if compacted:
             return query_topk(
                 idx, qb, delta=delta, k=10, window=window,
-                backend="pallas_compact", interpret=interpret,
+                backend="pallas_compact",
                 live_q=live_q,
             )
         return query_topk(
             idx, qb, delta=delta, k=10, window=window,
-            backend="pallas", interpret=interpret,
+            backend="pallas",
         )
 
     for c in (True, False):                       # compile
@@ -155,7 +156,7 @@ def _compact_pair(idx, delta, qb, *, window, interpret, live_q=None, reps=9):
     return _stats(first), _stats(second), ratio
 
 
-def _grid_occupancy(idx, delta, qb, *, window, interpret, live_q=None):
+def _grid_occupancy(idx, delta, qb, *, window, live_q=None):
     """Mean ``odys_kernel_grid_occupancy`` across the kernel family for
     one compacted batch, captured through a scoped registry."""
     reg = MetricsRegistry()
@@ -163,7 +164,7 @@ def _grid_occupancy(idx, delta, qb, *, window, interpret, live_q=None):
     try:
         jax.block_until_ready(query_topk(
             idx, qb, delta=delta, k=10, window=window,
-            backend="pallas_compact", interpret=interpret, live_q=live_q,
+            backend="pallas_compact", live_q=live_q,
         ))
     finally:
         set_registry(prev)
@@ -197,8 +198,6 @@ def _report_index_bytes(idx):
 def main(backend: str = "jnp", smoke: bool = False, codec: str = "raw"):
     if codec not in ("raw", "packed"):
         raise ValueError(f"unknown codec {codec!r}")
-    on_tpu = jax.default_backend() == "tpu"
-    interpret = None if backend == "jnp" else (not on_tpu)
     n_docs, vocab, n_ops = (2_500, 500, 120) if smoke else (20_000, 2_000, 400)
     corpus = generate_corpus(
         CorpusConfig(n_docs=n_docs, vocab_size=vocab, mean_doc_len=60,
@@ -234,8 +233,8 @@ def main(backend: str = "jnp", smoke: bool = False, codec: str = "raw"):
     q = [(list(rng.integers(0, 64, size=2)), None) for _ in range(8)]
     qb = make_query_batch(q, t_max=4, meta=meta)
     window = 1024 if smoke else 4096
-    mode = "compiled" if on_tpu else (
-        "interpret" if backend == "pallas" else "jnp"
+    mode = "jnp" if backend == "jnp" else (
+        "interpret" if ops.resolve_interpret() else "compiled"
     )
 
     def _report(name, stats):
@@ -245,7 +244,7 @@ def main(backend: str = "jnp", smoke: bool = False, codec: str = "raw"):
         print(f"updates,{name}_min,{best:.1f},per_query_us_{mode}")
 
     nodelta_stats = _query_latency(
-        idx, None, qb, window=window, backend=backend, interpret=interpret,
+        idx, None, qb, window=window, backend=backend,
         codec=codec,
     )
     nodelta = nodelta_stats[0]
@@ -268,7 +267,7 @@ def main(backend: str = "jnp", smoke: bool = False, codec: str = "raw"):
             # codec before/after: packed in-kernel decode vs the raw
             # streamed path, interleaved for a stable gate ratio
             stats, rstats, ratio = _query_latency_pair(
-                idx, delta, qb, window=window, interpret=interpret,
+                idx, delta, qb, window=window,
                 variants=(("pallas", "packed"), ("pallas", "raw")),
             )
             lat[target] = stats[0]
@@ -280,7 +279,7 @@ def main(backend: str = "jnp", smoke: bool = False, codec: str = "raw"):
             # before/after: the legacy gather + host-sort data path,
             # interleaved with the streamed path for a stable gate ratio
             stats, sstats, ratio = _query_latency_pair(
-                idx, delta, qb, window=window, interpret=interpret
+                idx, delta, qb, window=window
             )
             lat[target] = stats[0]
             lat_staged[target] = sstats[0]
@@ -290,7 +289,7 @@ def main(backend: str = "jnp", smoke: bool = False, codec: str = "raw"):
                   f"{ratio:.3f},median_interleaved_rep_ratio")
         else:
             stats = _query_latency(idx, delta, qb, window=window,
-                                   backend=backend, interpret=interpret,
+                                   backend=backend,
                                    codec=codec)
             lat[target] = stats[0]
             _report(f"query_fill{fill}", stats)
@@ -326,11 +325,11 @@ def main(backend: str = "jnp", smoke: bool = False, codec: str = "raw"):
         live_q = np.array([True] * 5 + [False] * 3)
         skew_qb = make_query_batch(skew_q, t_max=4, meta=meta)
         occ = _grid_occupancy(idx, wl_delta, skew_qb, window=window,
-                              interpret=interpret, live_q=live_q)
+                              live_q=live_q)
         print(f"updates,kernel_grid_occupancy_skew,{occ:.3f},"
               f"live_items_over_dense_steps")
         cstats, dstats, ratio = _compact_pair(
-            idx, wl_delta, skew_qb, window=window, interpret=interpret,
+            idx, wl_delta, skew_qb, window=window,
             live_q=live_q,
         )
         _report("query_skew_compact", cstats)
@@ -338,7 +337,7 @@ def main(backend: str = "jnp", smoke: bool = False, codec: str = "raw"):
         print(f"updates,compact_over_dense_skew,{ratio:.3f},"
               f"median_interleaved_rep_ratio")
         cstats, dstats, ratio = _compact_pair(
-            idx, wl_delta, qb, window=window, interpret=interpret,
+            idx, wl_delta, qb, window=window,
         )
         _report("query_uniform_compact", cstats)
         _report("query_uniform_dense", dstats)
@@ -359,7 +358,7 @@ def main(backend: str = "jnp", smoke: bool = False, codec: str = "raw"):
     else:
         delta0 = local_delta(writer2.device_delta())
     dt, _, _ = _query_latency(new_local, delta0, qb, window=window,
-                              backend=backend, interpret=interpret,
+                              backend=backend,
                               codec=codec)
     print(f"updates,query_post_compaction,{dt/len(q)*1e6:.1f},"
           f"per_query_us_{mode}")

@@ -78,6 +78,7 @@ from benchmarks import (  # noqa: E402  (jax env flags must be set first)
     bench_table3,
     bench_updates,
 )
+from repro.launch.compile_cache import use_checkout_cache  # noqa: E402
 
 SUITES = {
     "table3": bench_table3.main,    # Table 3 parameters + derived ST/weights
@@ -165,6 +166,7 @@ def main() -> None:
              "consumed by scripts/check_bench.py)",
     )
     args = ap.parse_args()
+    use_checkout_cache()
     sets = (
         [int(s) for s in args.sets.split(",") if s.strip()]
         if args.sets else None

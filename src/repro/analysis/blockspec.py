@@ -15,7 +15,7 @@ from typing import Iterator, Sequence
 
 import numpy as np
 
-from repro.kernels.registry import UNBLOCKED, OperandContract
+from repro.kernels.registry import ELEMENT, OperandContract
 
 #: Minimum tile of a TPU vector register, by dtype itemsize: the second-
 #: minor block dim must be a multiple of the sublane count, the minor dim
@@ -51,10 +51,10 @@ def block_origin(
 ) -> tuple[int, ...]:
     """Element-space origin of the mapped block.
 
-    Blocked mode scales the map's output by the block shape; unblocked
+    Blocked mode scales the map's output by the block shape; Element
     mode treats it as an element offset directly.
     """
-    if op.indexing_mode == UNBLOCKED:
+    if op.indexing_mode == ELEMENT:
         return tuple(int(m) for m in mapped)
     return tuple(int(m) * b for m, b in zip(mapped, op.block_shape))
 
@@ -93,6 +93,29 @@ def alignment_errors(op: OperandContract) -> list[str]:
         errs.append(
             f"second-minor block dim {blk[-2]} is not a multiple of the "
             f"{sub}-sublane tile for itemsize {itemsize}"
+        )
+    return errs
+
+
+def element_offset_errors(
+    op: OperandContract, origin: tuple[int, ...]
+) -> list[str]:
+    """Tile alignment of an Element read's origin: Mosaic accepts an
+    Element offset only when it can prove the second-minor offset a
+    multiple of the dtype's sublane tile and the minor offset a multiple
+    of the 128 lanes."""
+    if op.indexing_mode != ELEMENT or len(origin) < 2:
+        return []
+    sub = SUBLANES_BY_ITEMSIZE.get(op.itemsize, 8)
+    errs = []
+    if origin[-2] % sub:
+        errs.append(
+            f"row offset {origin[-2]} is not a multiple of the {sub}-row "
+            f"sublane tile"
+        )
+    if origin[-1] % LANES:
+        errs.append(
+            f"lane offset {origin[-1]} is not a multiple of {LANES} lanes"
         )
     return errs
 

@@ -19,7 +19,9 @@ enumerates the grid and proves, without executing the kernel:
   contiguous in grid iteration order (Pallas only guarantees coherent
   output accumulation for contiguous revisits);
 - **alignment**: block shapes must be (sublane, 128)-tile aligned for
-  their dtype;
+  their dtype, and every offset an Element-indexed map returns must be a
+  multiple of that tile (Mosaic refuses an Element read it cannot prove
+  aligned: "Couldn't prove that tiling (8) divides index");
 - **vmem**: double-buffered blocks + scratch must fit the per-core budget.
 
 Every finding carries the kernel's registered ``file:line`` site.
@@ -31,7 +33,7 @@ import dataclasses
 from typing import Sequence
 
 from repro.analysis import blockspec
-from repro.kernels.registry import KernelContract, load_contracts
+from repro.kernels.registry import ELEMENT, KernelContract, load_contracts
 
 #: Default per-core VMEM budget (bytes) — v4/v5 class cores carry 16 MiB.
 DEFAULT_VMEM_BUDGET = 16 * 1024 * 1024
@@ -119,14 +121,15 @@ def _check_spare_tile(c: KernelContract) -> list[Finding]:
             )
             continue
         slack = op.array_elems - op.padding_from
-        if slack < op.block_elems:
+        need = op.block_elems if op.spare_elems is None else op.spare_elems
+        if slack < need:
             finds.append(
                 Finding(
                     c.name,
                     "spare-tile",
                     f"{op.name!r}: only {slack} padded elements past the "
-                    f"live extent {op.padding_from}, need a whole spare "
-                    f"block ({op.block_elems}) — an edge-clamped read can "
+                    f"live extent {op.padding_from}, need a spare region "
+                    f"of {need} — an edge-clamped read can "
                     f"land on live data (flat_tile_pad must round UP before "
                     f"adding the spare tile)",
                     c.site,
@@ -197,6 +200,24 @@ def _check_alignment(c: KernelContract) -> list[Finding]:
             finds.append(
                 Finding(c.name, "alignment", f"{op.name!r}: {err}", c.site)
             )
+        if op.indexing_mode != ELEMENT:
+            continue
+        for point in blockspec.iter_grid(c.grid):
+            origin = blockspec.block_origin(
+                op, blockspec.eval_map(op.index_map, point, c.scalars)
+            )
+            errs = blockspec.element_offset_errors(op, origin)
+            if errs:
+                finds.append(
+                    Finding(
+                        c.name,
+                        "alignment",
+                        f"{op.name!r}: Element read at grid point {point}: "
+                        + "; ".join(errs),
+                        c.site,
+                    )
+                )
+                break
     for i, (shape, dtype) in enumerate(c.scratch):
         if len(shape) < 2:
             continue  # small 1-D scratch is register/SMEM-resident

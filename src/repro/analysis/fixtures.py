@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.kernels.registry import UNBLOCKED, KernelContract, OperandContract
+from repro.kernels.registry import ELEMENT, KernelContract, OperandContract
 
 _SITE = "src/repro/analysis/fixtures.py"
 
@@ -81,7 +81,7 @@ def broken_contracts() -> list[tuple[KernelContract, str]]:
         )
     )
 
-    # Unblocked stream over a flat array whose padding stops exactly at
+    # Element stream over a flat array whose padding stops exactly at
     # the live extent: no spare tile for the edge clamp to land in.
     out.append(
         (
@@ -97,7 +97,7 @@ def broken_contracts() -> list[tuple[KernelContract, str]]:
                         "int32",
                         TILE8,
                         _edge_clamp_map,
-                        indexing_mode=UNBLOCKED,
+                        indexing_mode=ELEMENT,
                         padding_from=32 * 128,  # live to the very end
                         spare_tile=True,
                     ),
@@ -124,7 +124,7 @@ def broken_contracts() -> list[tuple[KernelContract, str]]:
                         "int32",
                         TILE8,
                         _edge_clamp_map,
-                        indexing_mode=UNBLOCKED,
+                        indexing_mode=ELEMENT,
                         intended_map=_edge_clamp_intended,
                         consumed=_consume_all,
                         padding_from=24 * 128,
@@ -157,7 +157,8 @@ def broken_contracts() -> list[tuple[KernelContract, str]]:
     woff = np.asarray(pk.blk_woff)
 
     def _truncated_packed_map(i, woff_ref):
-        return (int(np.minimum(woff_ref[i] // 128, rows_t - cr)), 0)
+        row = int(np.minimum(woff_ref[i] // 128, rows_t - cr))
+        return (row // 8 * 8, 0)
 
     out.append(
         (
@@ -173,7 +174,7 @@ def broken_contracts() -> list[tuple[KernelContract, str]]:
                         "int32",
                         (cr, 128),
                         _truncated_packed_map,
-                        indexing_mode=UNBLOCKED,
+                        indexing_mode=ELEMENT,
                         padding_from=live_w,
                         spare_tile=True,
                     ),
@@ -181,6 +182,37 @@ def broken_contracts() -> list[tuple[KernelContract, str]]:
                 outputs=(_flat_op("o", 4, _id_map),),
             ),
             "spare-tile",
+        )
+    )
+
+    # The driver window as the streamed kernels first read it: an Element
+    # read of one (8, 128) tile at the list's BLOCK-aligned start row
+    # (offset 384 -> row 3).  Interpret mode runs it; Mosaic refuses it
+    # ("Couldn't prove that tiling (8) divides index"), and so must the
+    # checker.
+    def _block_aligned_window_map(i, info_ref):
+        return (int(info_ref[0]) + i * 8, 0)
+
+    out.append(
+        (
+            KernelContract(
+                name="fx_block_aligned_driver_window",
+                site=_line("fx_block_aligned_driver_window"),
+                grid=(2,),
+                scalars=(np.array([384 // 128], np.int32),),
+                inputs=(
+                    OperandContract(
+                        "postings(driver)",
+                        (32, 128),
+                        "int32",
+                        TILE8,
+                        _block_aligned_window_map,
+                        indexing_mode=ELEMENT,
+                    ),
+                ),
+                outputs=(_flat_op("o", 2, _id_map),),
+            ),
+            "alignment",
         )
     )
 

@@ -341,7 +341,7 @@ class _FileLinter(ast.NodeVisitor):
                         "interpret-literal",
                         f"interpret={kw.value.value} hard-coded at a call "
                         "site — thread it (default None resolves via "
-                        "ops.default_interpret())",
+                        "ops.resolve_interpret())",
                         kw.value,
                     )
         self.generic_visit(node)

@@ -48,7 +48,7 @@ obtains per-(query, term) posting streams through a
   window's tile spans (:class:`DriverSpan` — the window start in the flat
   arrays plus its live-posting count) and the kernel reads driver tiles
   straight from the flat ``postings``/``attrs`` arrays through
-  unblocked-index BlockSpecs, emitting the window as kernel *output* (the
+  aligned Element-indexed BlockSpecs, emitting the window as kernel *output* (the
   one materialization the ZigZag join fundamentally needs, since the
   result is selected from it); *other-term* streams are probed in place —
   the jnp backend with ``searchsorted`` over the term's window, the
@@ -292,8 +292,8 @@ class DriverSpan(NamedTuple):
     This is what a PostingSource hands the streaming kernels *instead of*
     a materialized ``(Q, window)`` gather: the window's start offset in
     the flat arrays (BLOCK-aligned, every list start is) and how many of
-    its slots hold live postings.  The kernels turn it into unblocked-
-    index BlockSpec offsets and read the driver tiles straight from HBM.
+    its slots hold live postings.  The kernels turn it into aligned
+    Element BlockSpec reads and read the driver tiles straight from HBM.
     """
 
     off: jnp.ndarray    # int32[Q] window start in the flat arrays
@@ -867,8 +867,9 @@ def _query_topk_jitted(
     if backend in ("pallas", "pallas_staged"):
         from repro.kernels import ops
 
-        if interpret is None:
-            interpret = ops.default_interpret()
+        interpret = ops.resolve_interpret(
+            interpret, packed=backend == "pallas" and codec == "packed"
+        )
         if backend == "pallas":
             return _query_topk_batch_pallas(
                 index,
@@ -945,8 +946,7 @@ def query_topk(
             )
     from repro.kernels import ops
 
-    if interpret is None:
-        interpret = ops.default_interpret()
+    interpret = ops.resolve_interpret(interpret, packed=codec == "packed")
     return _query_topk_batch_pallas_compact(
         index, batch, k=k, window=window, attr_strategy=attr_strategy,
         interpret=interpret, delta=delta, use_packed=codec == "packed",

@@ -63,10 +63,10 @@ def flat_tile_pad(n: int) -> int:
 
     TILE-aligned, with at least one whole spare INVALID tile past the last
     valid posting.  The spare tile is a *load-bearing* invariant of the
-    streamed read path: driver windows are addressed with unblocked-index
-    BlockSpecs at BLOCK (not TILE) granularity, and a window tile whose
-    read would run off the end of the array is clamped by Pallas to the
-    last resident tile.  The spare tile guarantees any such clamped tile
+    streamed read path: driver windows start at BLOCK (not TILE)
+    granularity, are read as the aligned tiles covering them, and a window
+    tile whose read would run off the end of the array is clamped to the
+    read that ends at the array edge.  The spare tile guarantees any such clamped tile
     lies entirely past every list's live range, so the kernels' intended-
     position masking discards all of it — clamping can shift *which* data
     arrives, never which data is *kept*.  Both the main index build and the
@@ -112,7 +112,7 @@ class FlatPadding(NamedTuple):
 
     def spare_tile_ok(self, read_elems: int = TILE) -> bool:
         """True iff a clamped ``read_elems``-sized edge read lies entirely
-        past the live extent (the invariant unblocked-index BlockSpecs
+        past the live extent (the invariant edge-clamped window reads
         rely on)."""
         return self.padded_len - read_elems >= self.live_extent
 
@@ -285,15 +285,17 @@ def pack_flat_postings(
 
     # The fixed chunk read covering any span_blocks consecutive blocks:
     # worst case over every start block of (words spanned, rounded out to
-    # whole 128-word rows from the start block's row).
+    # whole 128-word rows from the start block's row rounded down to the
+    # 8-sublane tile — chunk reads start on tile boundaries, the only
+    # Element offsets Mosaic accepts).
+    sub = TILE // BLOCK
     span = max(DESC_PAD, int(span_blocks))
     b0 = np.arange(n_blocks, dtype=np.int64)
     end = np.minimum(b0 + span, n_blocks)
-    r0 = woff[b0] // BLOCK
+    r0 = (woff[b0] // BLOCK) // sub * sub
     rows_needed = -(-(woff[end] - r0 * BLOCK) // BLOCK)
     # Rounded up to the 8-sublane tile: chunk BlockSpecs must stay
     # (8, 128)-aligned like every other int32 block.
-    sub = TILE // BLOCK
     chunk_rows = int(max(1, rows_needed.max(initial=1)))
     chunk_rows = -(-chunk_rows // sub) * sub
 
@@ -436,7 +438,7 @@ def _build_numpy(
     # TILE-align the flat arrays with a spare INVALID tile (flat_tile_pad):
     # the streaming kernels address postings as whole (8, 128) VMEM tiles
     # straight from HBM — including the *driver* window, read at BLOCK
-    # granularity via unblocked BlockSpecs — with no per-query gather.
+    # granularity via aligned Element reads — with no per-query gather.
     total = flat_tile_pad(total)
 
     postings = np.full(total, INVALID_DOC, dtype=np.int32)

@@ -56,7 +56,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.compat import shard_map
 from repro.core.engine import QueryBatch, query_topk
 from repro.core.index import (
     InvertedIndex,
@@ -168,7 +167,7 @@ def distributed_query_topk(
     delta_spec = jax.tree.map(lambda _: P(axis), delta)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(index_spec, batch_spec, delta_spec),
         out_specs=SearchResult(P(), P()),
@@ -235,7 +234,7 @@ def slave_topk_unmerged(
     delta_spec = jax.tree.map(lambda _: P(axis), delta)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(index_spec, batch_spec, delta_spec),
         out_specs=SearchResult(P(axis), P(axis)),
@@ -294,7 +293,7 @@ def replicated_query_topk(
     delta_spec = jax.tree.map(lambda _: P(None, axis), pod_delta)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(index_spec, batch_spec, delta_spec),
         out_specs=SearchResult(P(pod_axis), P(pod_axis)),

@@ -10,21 +10,25 @@ does the merge where the data already is, and reads both inputs where
 
 - the **main window is streamed straight from the flat posting arrays**:
   per-query window offsets (``m_off``/``m_neff``, from the engine's
-  PostingSource layer) are scalar-prefetched and an unblocked-index
-  BlockSpec walks the window tile-by-tile — the former ``(Q, window)``
-  host-side driver gather no longer exists.  Each tile is masked to the
-  window's live range by its *intended* position, so the spare INVALID
-  tile every flat array carries (:func:`repro.core.index.flat_tile_pad`)
-  makes edge-clamped reads provably harmless;
+  PostingSource layer) are scalar-prefetched and an Element-indexed
+  BlockSpec walks the window tile-by-tile, reading the two aligned tiles
+  that cover each window tile (:func:`repro.kernels.posting_intersect.
+  window_read`) — the former ``(Q, window)`` host-side driver gather no
+  longer exists.  Each tile is masked to the window's live range by its
+  *intended* position, so the spare INVALID tile every flat array carries
+  (:func:`repro.core.index.flat_tile_pad`) makes edge-clamped reads
+  provably harmless;
 - both inputs are sorted (the main window ascending by construction, the
   delta slab ascending per list), so the merge is a single **bitonic merge
   pass** — ``log2(N)`` data-independent compare-exchange stages over the
   concatenation of the main stream and the *reversed* delta stream (an
   ascending-then-descending, i.e. bitonic, sequence) — not a full
-  ``O(log^2 N)`` sort;
+  ``O(log^2 N)`` sort.  The network runs on ``(rows, 128)`` VMEM values
+  with lane and sublane rotates (:mod:`repro.kernels.bitonic`);
 - the delta slab is **streamed straight from the flat delta arrays** via a
   scalar-prefetched slab index in the BlockSpec index map (no per-query
-  gather of delta postings);
+  gather of delta postings), read as whole sublane tiles from the tile
+  boundary at or before the slab's first row;
 - the delta's **block-max skip table** is read per query: a slab whose
   occupied-block count is zero short-circuits the whole network to a
   copy-through (at 0% fill the merge costs one VMEM copy);
@@ -60,12 +64,18 @@ from repro.core.index import (
     PackedFlatArrays,
     pack_flat_postings,
 )
+from repro.kernels import bitonic
 from repro.kernels.posting_intersect import (
+    _CANON_LISTS,
+    _CANON_OFF,
     LANES,
     TILE_ROWS,
+    WINDOW_READ_ROWS,
     _decode_span,
     _packed_row0,
     _tile_positions,
+    element_spec,
+    window_read,
 )
 from repro.kernels.worklist import (
     FLAG_LAST,
@@ -81,32 +91,38 @@ def _next_pow2(n: int) -> int:
     return 1 << max(0, (n - 1)).bit_length()
 
 
-def _exchange(x, swap, blocks, d, n):
-    """One compare-exchange stage applied to a rider array ``x``."""
-    x2 = x.reshape(blocks, 2, d)
-    lo = jnp.where(swap, x2[:, 1], x2[:, 0])
-    hi = jnp.where(swap, x2[:, 0], x2[:, 1])
-    return jnp.stack([lo, hi], axis=1).reshape(n)
+def _slab_geometry(cap: int) -> tuple[int, int, int, int]:
+    """Row geometry of a delta slab of ``cap`` postings:
+    ``(cap_rows, load_rows, read_rows, net_rows)``.
+
+    ``load_rows`` is ``cap_rows`` rounded up to the sublane tile: the
+    kernel loads that many rows from the slab's row shift inside an
+    aligned ``read_rows = load_rows + TILE_ROWS`` read, so the load always
+    fits.  ``net_rows`` pads the load to a power of two for the merge
+    network's reversal stage.
+    """
+    cap_rows = cap // LANES
+    load_rows = -(-cap_rows // TILE_ROWS) * TILE_ROWS
+    return cap_rows, load_rows, load_rows + TILE_ROWS, _next_pow2(load_rows)
 
 
-def _bitonic_merge_flat(key, src, payloads):
-    """Ascending merge of a bitonic ``key`` sequence, ties broken by
-    ``src`` (stream id); ``payloads`` travel with their key."""
-    n = key.shape[0]
-    log_n = n.bit_length() - 1
-    assert 1 << log_n == n, "bitonic merge needs power-of-two length"
-    for j in range(log_n - 1, -1, -1):
-        d = 1 << j
-        blocks = n // (2 * d)
-        k2 = key.reshape(blocks, 2, d)
-        s2 = src.reshape(blocks, 2, d)
-        swap = (k2[:, 0] > k2[:, 1]) | (
-            (k2[:, 0] == k2[:, 1]) & (s2[:, 0] > s2[:, 1])
-        )
-        key = _exchange(key, swap, blocks, d, n)
-        src = _exchange(src, swap, blocks, d, n)
-        payloads = tuple(_exchange(p, swap, blocks, d, n) for p in payloads)
-    return key, src, payloads
+def _slab_read(slab_row, rows_d: int, read_rows: int):
+    """``(start, shift)`` of the aligned ``read_rows``-row read holding the
+    slab that starts at ``slab_row``.  The clamp never engages: the spare
+    tile past the last slab leaves room for the read of any slab start
+    rounded down to a tile (the contract checker verifies it)."""
+    start = (
+        jnp.minimum(slab_row, rows_d - read_rows) // TILE_ROWS
+    ) * TILE_ROWS
+    return start, jnp.minimum(slab_row - start, TILE_ROWS)
+
+
+def _pad_rows(x, rows: int, fill):
+    if x.shape[0] == rows:
+        return x
+    return jnp.concatenate(
+        [x, jnp.full((rows - x.shape[0], LANES), fill, x.dtype)], axis=0
+    )
 
 
 # BlockSpec index maps — module-level so the contract checker
@@ -116,19 +132,22 @@ def _bitonic_merge_flat(key, src, payloads):
 
 def _main_window_map(rows_total):
     def m_map(q, j, minfo_ref, slab_ref, len_ref, occ_ref, *_):
-        # Unblocked element-row offset of window tile j; clamped at the
-        # array edge (spare-tile invariant keeps clamped tiles masked).
-        row = minfo_ref[q, 0] + j * TILE_ROWS
-        return (jnp.minimum(row, rows_total - TILE_ROWS), 0)
+        # The aligned read covering window tile j; clamped at the array
+        # edge (spare-tile invariant keeps clamped tiles masked).
+        return (window_read(minfo_ref[q, 0] + j * TILE_ROWS, rows_total)[0], 0)
 
     return m_map
 
 
-def _slab_map(q, j, minfo_ref, slab_ref, len_ref, occ_ref, *_):
-    # empty slabs pin to block 0: the copy-through never reads the
-    # operand, and consecutive skipped queries coalesce onto one
-    # already-resident block instead of one slab DMA each
-    return (jnp.where(occ_ref[q] == 0, 0, slab_ref[q]), 0)
+def _slab_map(rows_d, read_rows, cap_rows):
+    def d_map(q, j, minfo_ref, slab_ref, len_ref, occ_ref, *_):
+        # empty slabs pin to slab 0: the copy-through never reads the
+        # operand, and consecutive skipped queries coalesce onto one
+        # already-resident block instead of one slab DMA each
+        slab = jnp.where(occ_ref[q] == 0, 0, slab_ref[q])
+        return (_slab_read(slab * cap_rows, rows_d, read_rows)[0], 0)
+
+    return d_map
 
 
 def _merge_out_map(q, j, minfo_ref, slab_ref, len_ref, occ_ref, *_):
@@ -162,22 +181,106 @@ def _packed_slab_map(woff_idx, bpt, n_blocks, rows_w, chunk_rows):
     return d_map
 
 
+def _main_tile(q, j, minfo_ref, mp_ref, ma_ref, *, rows_total, packed_m,
+               desc_m):
+    """Window tile ``j`` of query ``q``'s main window: (docs, attrs), each
+    (8, 128), masked to the live range by intended position."""
+    row = minfo_ref[q, 0] + j * TILE_ROWS
+    _, shift = window_read(row, rows_total)
+    in_win = _tile_positions(j) < minfo_ref[q, 1]
+    if packed_m is not None:
+        n_bm, rows_wm, cr_m = packed_m
+        mba_ref, mme_ref, mwo_ref = desc_m
+        b0c = jnp.minimum(row, n_bm)
+        row0 = _packed_row0(mwo_ref, b0c, rows_wm, cr_m)
+        m_tile = _decode_span(
+            mp_ref[...], mba_ref, mme_ref, mwo_ref, b0c, row0, TILE_ROWS
+        )
+    else:
+        m_tile = mp_ref[pl.ds(shift, TILE_ROWS), :]
+    return (
+        jnp.where(in_win, m_tile, INVALID_DOC),
+        jnp.where(in_win, ma_ref[pl.ds(shift, TILE_ROWS), :], INVALID_ATTR),
+    )
+
+
+def _delta_slab(q, slab_ref, occ_ref, dp_ref, da_ref, *, cap, rows_d,
+                packed_d, desc_d):
+    """Query ``q``'s delta slab as (docs, attrs), each (net_rows, 128):
+    the slab's postings first, then whatever follows it (masked by the
+    caller through the slab length)."""
+    cap_rows, load_rows, read_rows, net_rows = _slab_geometry(cap)
+    slab = jnp.where(occ_ref[q] == 0, 0, slab_ref[q])
+    _, shift = _slab_read(slab * cap_rows, rows_d, read_rows)
+    da = da_ref[pl.ds(shift, load_rows), :]
+    if packed_d is not None:
+        n_bd, rows_wd, cr_d = packed_d
+        dba_ref, dme_ref, dwo_ref = desc_d
+        # Same address arithmetic as _packed_slab_map so the decode
+        # offsets match the chunk the BlockSpec actually loaded.
+        b0d = jnp.minimum(slab * cap_rows, n_bd)
+        row0d = _packed_row0(dwo_ref, b0d, rows_wd, cr_d)
+        dd = _pad_rows(
+            _decode_span(
+                dp_ref[...], dba_ref, dme_ref, dwo_ref, b0d, row0d, cap_rows
+            ),
+            load_rows,
+            INVALID_DOC,
+        )
+    else:
+        dd = dp_ref[pl.ds(shift, load_rows), :]
+    return (
+        _pad_rows(dd, net_rows, INVALID_DOC),
+        _pad_rows(da, net_rows, INVALID_ATTR),
+    )
+
+
+def _merged_window(md, ma, dd, da, d_len, n_rows: int):
+    """Bitonic merge of the main window ``md``/``ma`` ((out_rows, 128),
+    ascending, INVALID past its live range) with the delta slab
+    ``dd``/``da`` ((net_rows, 128), ascending in its first ``d_len``
+    slots).  Returns the first ``out_rows`` rows of the merged
+    (docs, attrs, src)."""
+    out_rows, net_rows = md.shape[0], dd.shape[0]
+    d_valid = bitonic.flat_index(dd.shape) < d_len
+    dd = jnp.where(d_valid, dd, INVALID_DOC)
+    da = jnp.where(d_valid, da, INVALID_ATTR)
+    pad = n_rows - out_rows - net_rows
+
+    def layout(main, delta, fill):
+        # ascending main ++ pad ++ descending delta = bitonic
+        parts = [main]
+        if pad:
+            parts.append(jnp.full((pad, LANES), fill, jnp.int32))
+        parts.append(bitonic.reverse(delta))
+        return jnp.concatenate(parts, axis=0)
+
+    key = layout(md, dd, INVALID_DOC)
+    attr = layout(ma, da, INVALID_ATTR)
+    row = jax.lax.broadcasted_iota(jnp.int32, key.shape, 0)
+    src = (row >= out_rows).astype(jnp.int32)
+    key, src, (attr,) = bitonic.merge(key, src, (attr,))
+    return key[:out_rows], attr[:out_rows], src[:out_rows]
+
+
 def _merge_kernel(
     # raw refs: [minfo, slab, d_len, d_occ] scalars, then
-    #   mp (8,128) window tile / ma (8,128) attrs /
-    #   dp (cap/128,128) slab docids / da slab attrs,
+    #   mp (16,128) aligned window read / ma the same read of the attrs /
+    #   dp (read_rows,128) aligned slab read / da the same for attrs,
     #   od/oa/os (1,out_rows,128) outputs, sd/sa (out_rows,128) scratch.
     # packed mode appends six descriptor scalars
     #   [m_base, m_meta, m_woff, d_base, d_meta, d_woff]
     # and mp/dp become packed-word chunks (chunk_rows, 128); attrs stay raw.
     *refs,
-    out_w: int,
     cap: int,
-    n_pad: int,
+    n_rows: int,
     s_w: int,
+    rows_total: int,
+    rows_d: int,
     packed_m=None,  # (n_blocks, rows_w, chunk_rows) of the main words
     packed_d=None,  # same for the delta words
 ):
+    desc_m = desc_d = None
     if packed_m is not None:
         (
             minfo_ref, slab_ref, len_ref, occ_ref,
@@ -185,6 +288,8 @@ def _merge_kernel(
             mp_ref, ma_ref, dp_ref, da_ref,
             od_ref, oa_ref, os_ref, sd_ref, sa_ref,
         ) = refs
+        desc_m = (mba_ref, mme_ref, mwo_ref)
+        desc_d = (dba_ref, dme_ref, dwo_ref)
     else:
         (
             minfo_ref, slab_ref, len_ref, occ_ref,
@@ -198,22 +303,13 @@ def _merge_kernel(
     # Accumulate this window tile into scratch, masked to the live range by
     # its intended window position (tiles are window-aligned): a clamped
     # edge read can only affect fully-masked slots (spare-tile invariant).
-    in_win = _tile_positions(j) < minfo_ref[q, 1]
-    if packed_m is not None:
-        n_bm, rows_wm, cr_m = packed_m
-        b0c = jnp.minimum(minfo_ref[q, 0] + j * TILE_ROWS, n_bm)
-        row0 = _packed_row0(mwo_ref, b0c, rows_wm, cr_m)
-        m_tile = _decode_span(
-            mp_ref[...], mba_ref, mme_ref, mwo_ref, b0c, row0, TILE_ROWS
-        )
-    else:
-        m_tile = mp_ref[...]
-    sd_ref[pl.dslice(j * TILE_ROWS, TILE_ROWS), :] = jnp.where(
-        in_win, m_tile, INVALID_DOC
+    m_tile, a_tile = _main_tile(
+        q, j, minfo_ref, mp_ref, ma_ref,
+        rows_total=rows_total, packed_m=packed_m, desc_m=desc_m,
     )
-    sa_ref[pl.dslice(j * TILE_ROWS, TILE_ROWS), :] = jnp.where(
-        in_win, ma_ref[...], INVALID_ATTR
-    )
+    rows = pl.ds(pl.multiple_of(j * TILE_ROWS, TILE_ROWS), TILE_ROWS)
+    sd_ref[rows, :] = m_tile
+    sa_ref[rows, :] = a_tile
 
     # Skip-table short-circuit: an empty slab merges to the main window.
     @pl.when((j == s_w - 1) & (occ_ref[q] == 0))
@@ -224,44 +320,35 @@ def _merge_kernel(
 
     @pl.when((j == s_w - 1) & (occ_ref[q] != 0))
     def _merge():
-        md = sd_ref[...].reshape(-1)
-        ma = sa_ref[...].reshape(-1)
-        d_valid = jnp.arange(cap, dtype=jnp.int32) < len_ref[q]
-        if packed_d is not None:
-            n_bd, rows_wd, cr_d = packed_d
-            bpt = cap // BLOCK
-            # Same address arithmetic as _packed_slab_map so the decode
-            # offsets match the chunk the BlockSpec actually loaded.
-            b0d = jnp.minimum(
-                jnp.where(occ_ref[q] == 0, 0, slab_ref[q]) * bpt, n_bd
-            )
-            row0d = _packed_row0(dwo_ref, b0d, rows_wd, cr_d)
-            dd_raw = _decode_span(
-                dp_ref[...], dba_ref, dme_ref, dwo_ref, b0d, row0d, bpt
-            ).reshape(-1)
-        else:
-            dd_raw = dp_ref[...].reshape(-1)
-        dd = jnp.where(d_valid, dd_raw, INVALID_DOC)
-        da = jnp.where(d_valid, da_ref[...].reshape(-1), INVALID_ATTR)
+        dd, da = _delta_slab(
+            q, slab_ref, occ_ref, dp_ref, da_ref,
+            cap=cap, rows_d=rows_d, packed_d=packed_d, desc_d=desc_d,
+        )
+        key, attr, src = _merged_window(
+            sd_ref[...], sa_ref[...], dd, da, len_ref[q], n_rows
+        )
+        od_ref[0] = key
+        oa_ref[0] = attr
+        os_ref[0] = src
 
-        # ascending main ++ pad ++ descending delta = bitonic
-        pad = n_pad - out_w - cap
-        key = jnp.concatenate(
-            [md, jnp.full((pad,), INVALID_DOC, jnp.int32), dd[::-1]]
-        )
-        attr = jnp.concatenate(
-            [ma, jnp.full((pad,), INVALID_ATTR, jnp.int32), da[::-1]]
-        )
-        src = jnp.concatenate(
-            [
-                jnp.zeros((out_w,), jnp.int32),
-                jnp.ones((n_pad - out_w,), jnp.int32),
-            ]
-        )
-        key, src, (attr,) = _bitonic_merge_flat(key, src, (attr,))
-        od_ref[0] = key[:out_w].reshape(od_ref.shape[1:])
-        oa_ref[0] = attr[:out_w].reshape(oa_ref.shape[1:])
-        os_ref[0] = src[:out_w].reshape(os_ref.shape[1:])
+
+def _merge_scalars(d_offsets, d_lengths, d_block_max, terms, m_off, m_neff):
+    """The four per-query scalar-prefetch operands every merge variant
+    shares: [minfo, slab, d_len, d_occ]."""
+    n_terms = d_offsets.shape[0]
+    cap = d_block_max.shape[0] * BLOCK // n_terms
+    bpt = cap // BLOCK
+    tt = jnp.clip(terms, 0, n_terms - 1)
+    slab = jnp.take(d_offsets, tt) // cap
+    d_len = jnp.where(terms < 0, 0, jnp.take(d_lengths, tt))
+    occ_per_term = jnp.sum(
+        d_block_max.reshape(n_terms, bpt) != INVALID_DOC, axis=1
+    ).astype(jnp.int32)
+    d_occ = jnp.where(terms < 0, 0, jnp.take(occ_per_term, tt))
+    minfo = jnp.stack(
+        [m_off.astype(jnp.int32) // LANES, m_neff.astype(jnp.int32)], axis=-1
+    )
+    return [minfo, slab, d_len, d_occ]
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -284,8 +371,8 @@ def merge_delta_windows(
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Merged (docs, attrs, src) driver windows, each int32[Q, window].
 
-    Both inputs stream from their flat arrays: the main window through an
-    unblocked-index BlockSpec at the scalar-prefetched per-query offset
+    Both inputs stream from their flat arrays: the main window through
+    aligned Element reads at the scalar-prefetched per-query offset
     (``m_off``/``m_neff`` — no host-side ``(Q, window)`` gather), the delta
     slab through its prefetched slab index.  ``src`` is each slot's stream
     id (0 = main, 1 = delta or padding); combined with the ``doc_flags``
@@ -302,8 +389,10 @@ def merge_delta_windows(
     cap = d_block_max.shape[0] * BLOCK // n_terms
     bpt = cap // BLOCK
     assert postings.shape[0] % TILE == 0, "main postings must be TILE-padded"
-    assert d_postings.shape[0] % LANES == 0
+    assert d_postings.shape[0] % TILE == 0, "delta must be TILE-padded"
     rows_total = postings.shape[0] // LANES
+    rows_d = d_postings.shape[0] // LANES
+    cap_rows, _, read_rows, net_rows = _slab_geometry(cap)
 
     # Tile-pad the window to whole (8, 128) reads; the pad slots carry
     # INVALID keys, which sort last, so merging the padded stream and
@@ -311,33 +400,21 @@ def merge_delta_windows(
     s_w = -(-window // TILE)
     out_w = s_w * TILE
     out_rows = s_w * TILE_ROWS
-
-    tt = jnp.clip(terms, 0, n_terms - 1)
-    slab = jnp.take(d_offsets, tt) // cap
-    d_len = jnp.where(terms < 0, 0, jnp.take(d_lengths, tt))
-    occ_per_term = jnp.sum(
-        d_block_max.reshape(n_terms, bpt) != INVALID_DOC, axis=1
-    ).astype(jnp.int32)
-    d_occ = jnp.where(terms < 0, 0, jnp.take(occ_per_term, tt))
-    minfo = jnp.stack(
-        [m_off.astype(jnp.int32) // LANES, m_neff.astype(jnp.int32)], axis=-1
-    )
+    n_rows = _next_pow2(out_w + net_rows * LANES) // LANES
 
     if (packed is None) != (d_packed is None):
         raise ValueError(
             "merge_delta_windows: packed and d_packed go together"
         )
 
-    n_pad = _next_pow2(out_w + cap)
-    cap_rows = cap // LANES
     ma2 = attrs.reshape(rows_total, LANES)
     da2 = d_attrs.reshape(-1, LANES)
-
     m_map = _main_window_map(rows_total)
-    d_map = _slab_map
-    o_map = _merge_out_map
+    d_map = _slab_map(rows_d, read_rows, cap_rows)
 
-    scalars = [minfo, slab, d_len, d_occ]
+    scalars = _merge_scalars(
+        d_offsets, d_lengths, d_block_max, terms, m_off, m_neff
+    )
     pk_m = pk_d = None
     if packed is not None:
         # Descriptor scalars ride after the raw four so every existing
@@ -351,28 +428,22 @@ def merge_delta_windows(
         pk_m = (packed.n_blocks, words_m2.shape[0], packed.chunk_rows)
         pk_d = (d_packed.n_blocks, words_d2.shape[0], d_packed.chunk_rows)
         in_specs = [
-            pl.BlockSpec(
-                (packed.chunk_rows, LANES),
-                _packed_window_map(6, *pk_m),
-                indexing_mode=pl.unblocked,
+            element_spec(packed.chunk_rows, _packed_window_map(6, *pk_m)),
+            element_spec(WINDOW_READ_ROWS, m_map),
+            element_spec(
+                d_packed.chunk_rows, _packed_slab_map(9, bpt, *pk_d)
             ),
-            pl.BlockSpec((TILE_ROWS, LANES), m_map, indexing_mode=pl.unblocked),
-            pl.BlockSpec(
-                (d_packed.chunk_rows, LANES),
-                _packed_slab_map(9, bpt, *pk_d),
-                indexing_mode=pl.unblocked,
-            ),
-            pl.BlockSpec((cap_rows, LANES), d_map),
+            element_spec(read_rows, d_map),
         ]
         operands = [words_m2, ma2, words_d2, da2]
     else:
         mp2 = postings.reshape(rows_total, LANES)
         dp2 = d_postings.reshape(-1, LANES)
         in_specs = [
-            pl.BlockSpec((TILE_ROWS, LANES), m_map, indexing_mode=pl.unblocked),
-            pl.BlockSpec((TILE_ROWS, LANES), m_map, indexing_mode=pl.unblocked),
-            pl.BlockSpec((cap_rows, LANES), d_map),
-            pl.BlockSpec((cap_rows, LANES), d_map),
+            element_spec(WINDOW_READ_ROWS, m_map),
+            element_spec(WINDOW_READ_ROWS, m_map),
+            element_spec(read_rows, d_map),
+            element_spec(read_rows, d_map),
         ]
         operands = [mp2, ma2, dp2, da2]
 
@@ -381,9 +452,9 @@ def merge_delta_windows(
         grid=(q_n, s_w),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, out_rows, LANES), o_map),
-            pl.BlockSpec((1, out_rows, LANES), o_map),
-            pl.BlockSpec((1, out_rows, LANES), o_map),
+            pl.BlockSpec((1, out_rows, LANES), _merge_out_map),
+            pl.BlockSpec((1, out_rows, LANES), _merge_out_map),
+            pl.BlockSpec((1, out_rows, LANES), _merge_out_map),
         ],
         scratch_shapes=[
             pltpu.VMEM((out_rows, LANES), jnp.int32),
@@ -394,10 +465,11 @@ def merge_delta_windows(
     docs, oattrs, src = pl.pallas_call(
         functools.partial(
             _merge_kernel,
-            out_w=out_w,
             cap=cap,
-            n_pad=n_pad,
+            n_rows=n_rows,
             s_w=s_w,
+            rows_total=rows_total,
+            rows_d=rows_d,
             packed_m=pk_m,
             packed_d=pk_d,
         ),
@@ -421,14 +493,18 @@ def merge_delta_windows(
 def _wl_main_window_map(rows_total):
     def m_map(n, desc_ref, minfo_ref, *_):
         row = minfo_ref[desc_ref[n, 0], 0] + desc_ref[n, 1] * TILE_ROWS
-        return (jnp.minimum(row, rows_total - TILE_ROWS), 0)
+        return (window_read(row, rows_total)[0], 0)
 
     return m_map
 
 
-def _wl_slab_map(n, desc_ref, minfo_ref, slab_ref, len_ref, occ_ref, *_):
-    q = desc_ref[n, 0]
-    return (jnp.where(occ_ref[q] == 0, 0, slab_ref[q]), 0)
+def _wl_slab_map(rows_d, read_rows, cap_rows):
+    def d_map(n, desc_ref, minfo_ref, slab_ref, len_ref, occ_ref, *_):
+        q = desc_ref[n, 0]
+        slab = jnp.where(occ_ref[q] == 0, 0, slab_ref[q])
+        return (_slab_read(slab * cap_rows, rows_d, read_rows)[0], 0)
+
+    return d_map
 
 
 def _wl_merge_out_map(n, desc_ref, *_):
@@ -464,12 +540,14 @@ def _merge_compact_kernel(
     # full-extent live mask at consume time — reproducing the dense
     # kernel's all-tiles in_win writes bit-exactly.
     *refs,
-    out_w: int,
     cap: int,
-    n_pad: int,
+    n_rows: int,
+    rows_total: int,
+    rows_d: int,
     packed_m=None,
     packed_d=None,
 ):
+    desc_m = desc_d = None
     if packed_m is not None:
         (
             wl_ref, minfo_ref, slab_ref, len_ref, occ_ref,
@@ -477,6 +555,8 @@ def _merge_compact_kernel(
             mp_ref, ma_ref, dp_ref, da_ref,
             od_ref, oa_ref, os_ref, sd_ref, sa_ref,
         ) = refs
+        desc_m = (mba_ref, mme_ref, mwo_ref)
+        desc_d = (dba_ref, dme_ref, dwo_ref)
     else:
         (
             wl_ref, minfo_ref, slab_ref, len_ref, occ_ref,
@@ -489,73 +569,37 @@ def _merge_compact_kernel(
     j = wl_ref[n, 1]
     flags = wl_ref[n, 4]
 
-    in_win = _tile_positions(j) < minfo_ref[q, 1]
-    if packed_m is not None:
-        n_bm, rows_wm, cr_m = packed_m
-        b0c = jnp.minimum(minfo_ref[q, 0] + j * TILE_ROWS, n_bm)
-        row0 = _packed_row0(mwo_ref, b0c, rows_wm, cr_m)
-        m_tile = _decode_span(
-            mp_ref[...], mba_ref, mme_ref, mwo_ref, b0c, row0, TILE_ROWS
-        )
-    else:
-        m_tile = mp_ref[...]
-    sd_ref[pl.dslice(j * TILE_ROWS, TILE_ROWS), :] = jnp.where(
-        in_win, m_tile, INVALID_DOC
+    m_tile, a_tile = _main_tile(
+        q, j, minfo_ref, mp_ref, ma_ref,
+        rows_total=rows_total, packed_m=packed_m, desc_m=desc_m,
     )
-    sa_ref[pl.dslice(j * TILE_ROWS, TILE_ROWS), :] = jnp.where(
-        in_win, ma_ref[...], INVALID_ATTR
-    )
+    rows = pl.ds(pl.multiple_of(j * TILE_ROWS, TILE_ROWS), TILE_ROWS)
+    sd_ref[rows, :] = m_tile
+    sa_ref[rows, :] = a_tile
 
-    def _live_full():
-        r = jax.lax.broadcasted_iota(jnp.int32, sd_ref.shape, 0)
-        c = jax.lax.broadcasted_iota(jnp.int32, sd_ref.shape, 1)
-        return (r * LANES + c) < minfo_ref[q, 1]
+    def _live_main():
+        live = bitonic.flat_index(sd_ref.shape) < minfo_ref[q, 1]
+        return (
+            jnp.where(live, sd_ref[...], INVALID_DOC),
+            jnp.where(live, sa_ref[...], INVALID_ATTR),
+        )
 
     @pl.when(((flags & FLAG_LAST) != 0) & (occ_ref[q] == 0))
     def _copy_through():
-        live = _live_full()
-        od_ref[0] = jnp.where(live, sd_ref[...], INVALID_DOC)
-        oa_ref[0] = jnp.where(live, sa_ref[...], INVALID_ATTR)
+        od_ref[0], oa_ref[0] = _live_main()
         os_ref[0] = jnp.zeros_like(os_ref[0])
 
     @pl.when(((flags & FLAG_LAST) != 0) & (occ_ref[q] != 0))
     def _merge():
-        live = _live_full()
-        md = jnp.where(live, sd_ref[...], INVALID_DOC).reshape(-1)
-        ma = jnp.where(live, sa_ref[...], INVALID_ATTR).reshape(-1)
-        d_valid = jnp.arange(cap, dtype=jnp.int32) < len_ref[q]
-        if packed_d is not None:
-            n_bd, rows_wd, cr_d = packed_d
-            bpt = cap // BLOCK
-            b0d = jnp.minimum(
-                jnp.where(occ_ref[q] == 0, 0, slab_ref[q]) * bpt, n_bd
-            )
-            row0d = _packed_row0(dwo_ref, b0d, rows_wd, cr_d)
-            dd_raw = _decode_span(
-                dp_ref[...], dba_ref, dme_ref, dwo_ref, b0d, row0d, bpt
-            ).reshape(-1)
-        else:
-            dd_raw = dp_ref[...].reshape(-1)
-        dd = jnp.where(d_valid, dd_raw, INVALID_DOC)
-        da = jnp.where(d_valid, da_ref[...].reshape(-1), INVALID_ATTR)
-
-        pad = n_pad - out_w - cap
-        key = jnp.concatenate(
-            [md, jnp.full((pad,), INVALID_DOC, jnp.int32), dd[::-1]]
+        md, ma = _live_main()
+        dd, da = _delta_slab(
+            q, slab_ref, occ_ref, dp_ref, da_ref,
+            cap=cap, rows_d=rows_d, packed_d=packed_d, desc_d=desc_d,
         )
-        attr = jnp.concatenate(
-            [ma, jnp.full((pad,), INVALID_ATTR, jnp.int32), da[::-1]]
-        )
-        src = jnp.concatenate(
-            [
-                jnp.zeros((out_w,), jnp.int32),
-                jnp.ones((n_pad - out_w,), jnp.int32),
-            ]
-        )
-        key, src, (attr,) = _bitonic_merge_flat(key, src, (attr,))
-        od_ref[0] = key[:out_w].reshape(od_ref.shape[1:])
-        oa_ref[0] = attr[:out_w].reshape(oa_ref.shape[1:])
-        os_ref[0] = src[:out_w].reshape(os_ref.shape[1:])
+        key, attr, src = _merged_window(md, ma, dd, da, len_ref[q], n_rows)
+        od_ref[0] = key
+        oa_ref[0] = attr
+        os_ref[0] = src
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -575,30 +619,22 @@ def _merge_compact_call(
     cap = d_block_max.shape[0] * BLOCK // n_terms
     bpt = cap // BLOCK
     rows_total = postings.shape[0] // LANES
+    rows_d = d_postings.shape[0] // LANES
+    cap_rows, _, read_rows, net_rows = _slab_geometry(cap)
     n_steps = desc.shape[0]
 
     s_w = -(-window // TILE)
     out_w = s_w * TILE
     out_rows = s_w * TILE_ROWS
+    n_rows = _next_pow2(out_w + net_rows * LANES) // LANES
 
-    tt = jnp.clip(terms, 0, n_terms - 1)
-    slab = jnp.take(d_offsets, tt) // cap
-    d_len = jnp.where(terms < 0, 0, jnp.take(d_lengths, tt))
-    occ_per_term = jnp.sum(
-        d_block_max.reshape(n_terms, bpt) != INVALID_DOC, axis=1
-    ).astype(jnp.int32)
-    d_occ = jnp.where(terms < 0, 0, jnp.take(occ_per_term, tt))
-    minfo = jnp.stack(
-        [m_off.astype(jnp.int32) // LANES, m_neff.astype(jnp.int32)], axis=-1
-    )
-
-    n_pad = _next_pow2(out_w + cap)
-    cap_rows = cap // LANES
     ma2 = attrs.reshape(rows_total, LANES)
     da2 = d_attrs.reshape(-1, LANES)
-
     m_map = _wl_main_window_map(rows_total)
-    scalars = [desc, minfo, slab, d_len, d_occ]
+    d_map = _wl_slab_map(rows_d, read_rows, cap_rows)
+    scalars = [desc] + _merge_scalars(
+        d_offsets, d_lengths, d_block_max, terms, m_off, m_neff
+    )
     pk_m = pk_d = None
     if packed is not None:
         scalars += [
@@ -610,28 +646,22 @@ def _merge_compact_call(
         pk_m = (packed.n_blocks, words_m2.shape[0], packed.chunk_rows)
         pk_d = (d_packed.n_blocks, words_d2.shape[0], d_packed.chunk_rows)
         in_specs = [
-            pl.BlockSpec(
-                (packed.chunk_rows, LANES),
-                _wl_packed_window_map(7, *pk_m),
-                indexing_mode=pl.unblocked,
+            element_spec(packed.chunk_rows, _wl_packed_window_map(7, *pk_m)),
+            element_spec(WINDOW_READ_ROWS, m_map),
+            element_spec(
+                d_packed.chunk_rows, _wl_packed_slab_map(10, bpt, *pk_d)
             ),
-            pl.BlockSpec((TILE_ROWS, LANES), m_map, indexing_mode=pl.unblocked),
-            pl.BlockSpec(
-                (d_packed.chunk_rows, LANES),
-                _wl_packed_slab_map(10, bpt, *pk_d),
-                indexing_mode=pl.unblocked,
-            ),
-            pl.BlockSpec((cap_rows, LANES), _wl_slab_map),
+            element_spec(read_rows, d_map),
         ]
         operands = [words_m2, ma2, words_d2, da2]
     else:
         mp2 = postings.reshape(rows_total, LANES)
         dp2 = d_postings.reshape(-1, LANES)
         in_specs = [
-            pl.BlockSpec((TILE_ROWS, LANES), m_map, indexing_mode=pl.unblocked),
-            pl.BlockSpec((TILE_ROWS, LANES), m_map, indexing_mode=pl.unblocked),
-            pl.BlockSpec((cap_rows, LANES), _wl_slab_map),
-            pl.BlockSpec((cap_rows, LANES), _wl_slab_map),
+            element_spec(WINDOW_READ_ROWS, m_map),
+            element_spec(WINDOW_READ_ROWS, m_map),
+            element_spec(read_rows, d_map),
+            element_spec(read_rows, d_map),
         ]
         operands = [mp2, ma2, dp2, da2]
 
@@ -650,9 +680,10 @@ def _merge_compact_call(
     docs, oattrs, src = pl.pallas_call(
         functools.partial(
             _merge_compact_kernel,
-            out_w=out_w,
             cap=cap,
-            n_pad=n_pad,
+            n_rows=n_rows,
+            rows_total=rows_total,
+            rows_d=rows_d,
             packed_m=pk_m,
             packed_d=pk_d,
         ),
@@ -740,7 +771,7 @@ def merge_delta_windows_compact(
 import numpy as np  # noqa: E402
 
 from repro.kernels.registry import (  # noqa: E402
-    UNBLOCKED,
+    ELEMENT,
     KernelContract,
     OperandContract,
     kernel_contract,
@@ -750,17 +781,29 @@ from repro.kernels.registry import (  # noqa: E402
 )
 
 
+#: Canonical delta slab capacity: the writer's default (two BLOCKs), so
+#: slabs start mid-tile and the aligned slab read's row shift is exercised.
+_CANON_CAP = 2 * BLOCK
+
+
+def _aligned(row):
+    return (row // TILE_ROWS) * TILE_ROWS
+
+
 def _main_window_intended(q, j, minfo_ref, slab_ref, len_ref, occ_ref, *_):
     """Pre-clamp address of :func:`_main_window_map` — contract only."""
-    return (minfo_ref[q, 0] + j * TILE_ROWS, 0)
+    return (_aligned(minfo_ref[q, 0] + j * TILE_ROWS), 0)
 
 
 def _main_window_consumed(q, j, minfo_ref, slab_ref, len_ref, occ_ref, *_):
     return bool(j * TILE < minfo_ref[q, 1])
 
 
-def _slab_intended(q, j, minfo_ref, slab_ref, len_ref, occ_ref, *_):
-    return (slab_ref[q], 0)
+def _slab_intended(cap_rows):
+    def intended(q, j, minfo_ref, slab_ref, len_ref, occ_ref, *_):
+        return (_aligned(slab_ref[q] * cap_rows), 0)
+
+    return intended
 
 
 def _slab_consumed(q, j, minfo_ref, slab_ref, len_ref, occ_ref, *_):
@@ -773,7 +816,7 @@ def _packed_window_intended(woff_idx, n_blocks):
 
     def intended(q, j, *refs):
         b0c = jnp.minimum(refs[0][q, 0] + j * TILE_ROWS, n_blocks)
-        return (refs[woff_idx][b0c] // LANES, 0)
+        return (_aligned(refs[woff_idx][b0c] // LANES), 0)
 
     return intended
 
@@ -782,18 +825,18 @@ def _packed_slab_intended(woff_idx, bpt, n_blocks):
     def intended(q, j, *refs):
         b0 = jnp.where(refs[3][q] == 0, 0, refs[1][q]) * bpt
         b0c = jnp.minimum(b0, n_blocks)
-        return (refs[woff_idx][b0c] // LANES, 0)
+        return (_aligned(refs[woff_idx][b0c] // LANES), 0)
 
     return intended
 
 
 def _build_merge_contract(use_packed):
-    # Canonical main index: lists (150, 100, 90); the last list ends
-    # mid-tile at the array edge, so the last window tile of query 1
-    # clamps — safe only because of the spare INVALID tile.
-    arrays, live = synthetic_flat_index((150, 100, 90))
-    delta = synthetic_delta_arrays(3, TILE, fills=(5, 0, 12))
-    n_terms, cap = 3, TILE
+    # Canonical main index (posting_intersect._CANON_LISTS): the last list
+    # ends mid-tile next to the spare tile, so the last window tile of
+    # query 1 clamps — safe only because of the spare INVALID tile.
+    arrays, live = synthetic_flat_index(_CANON_LISTS)
+    delta = synthetic_delta_arrays(3, _CANON_CAP, fills=(5, 0, 12))
+    n_terms, cap = 3, _CANON_CAP
     bpt = cap // BLOCK
     rows_total = arrays["postings"].shape[0] // LANES
 
@@ -802,7 +845,7 @@ def _build_merge_contract(use_packed):
     out_rows = s_w * TILE_ROWS
     q_n = 3
     terms = np.array([0, 2, -1], np.int32)
-    m_off = np.array([0, 384, 256], np.int32)
+    m_off = np.array([0, _CANON_OFF[2], _CANON_OFF[1]], np.int32)
     m_neff = np.array([150, 90, 100], np.int32)
 
     tt = np.clip(terms, 0, n_terms - 1)
@@ -815,17 +858,26 @@ def _build_merge_contract(use_packed):
     minfo = np.stack([m_off // LANES, m_neff], axis=-1).astype(np.int32)
     scalars = (minfo, slab.astype(np.int32), d_len, d_occ)
 
-    tile = (TILE_ROWS, LANES)
+    win = (WINDOW_READ_ROWS, LANES)
     flat_main = (rows_total, LANES)
-    cap_rows = cap // LANES
     flat_delta = (delta["d_postings"].shape[0] // LANES, LANES)
+    cap_rows, _, read_rows, _ = _slab_geometry(cap)
+    slab_blk = (read_rows, LANES)
+    d_map = _slab_map(flat_delta[0], read_rows, cap_rows)
     d_live = int(cap * n_terms)
     main_kw = dict(
-        indexing_mode=UNBLOCKED,
+        indexing_mode=ELEMENT,
         intended_map=_main_window_intended,
         consumed=_main_window_consumed,
         padding_from=live,
         spare_tile=True,
+        spare_elems=TILE,
+    )
+    slab_kw = dict(
+        indexing_mode=ELEMENT,
+        intended_map=_slab_intended(cap_rows),
+        consumed=_slab_consumed,
+        padding_from=d_live,
     )
     m_map = _main_window_map(rows_total)
     if use_packed:
@@ -846,7 +898,7 @@ def _build_merge_contract(use_packed):
             "int32",
             (pk_m.chunk_rows, LANES),
             _packed_window_map(6, pk_m.n_blocks, rows_wm, pk_m.chunk_rows),
-            indexing_mode=UNBLOCKED,
+            indexing_mode=ELEMENT,
             intended_map=_packed_window_intended(6, pk_m.n_blocks),
             consumed=_main_window_consumed,
             padding_from=int(np.asarray(pk_m.blk_woff)[-1]),
@@ -858,7 +910,7 @@ def _build_merge_contract(use_packed):
             "int32",
             (pk_d.chunk_rows, LANES),
             _packed_slab_map(9, bpt, pk_d.n_blocks, rows_wd, pk_d.chunk_rows),
-            indexing_mode=UNBLOCKED,
+            indexing_mode=ELEMENT,
             intended_map=_packed_slab_intended(9, bpt, pk_d.n_blocks),
             consumed=_slab_consumed,
             padding_from=int(np.asarray(pk_d.blk_woff)[-1]),
@@ -866,33 +918,19 @@ def _build_merge_contract(use_packed):
         )
     else:
         mp_op = OperandContract(
-            "main_postings", flat_main, "int32", tile, m_map, **main_kw
+            "main_postings", flat_main, "int32", win, m_map, **main_kw
         )
         dp_op = OperandContract(
-            "delta_postings",
-            flat_delta,
-            "int32",
-            (cap_rows, LANES),
-            _slab_map,
-            intended_map=_slab_intended,
-            consumed=_slab_consumed,
-            padding_from=d_live,
+            "delta_postings", flat_delta, "int32", slab_blk, d_map, **slab_kw
         )
     ins = (
         mp_op,
         OperandContract(
-            "main_attrs", flat_main, "int32", tile, m_map, **main_kw
+            "main_attrs", flat_main, "int32", win, m_map, **main_kw
         ),
         dp_op,
         OperandContract(
-            "delta_attrs",
-            flat_delta,
-            "int32",
-            (cap_rows, LANES),
-            _slab_map,
-            intended_map=_slab_intended,
-            consumed=_slab_consumed,
-            padding_from=d_live,
+            "delta_attrs", flat_delta, "int32", slab_blk, d_map, **slab_kw
         ),
     )
     blk_o = (1, out_rows, LANES)
@@ -934,7 +972,7 @@ def _contract_merge_delta_windows_packed():
 
 def _wl_main_window_intended(n, desc_ref, minfo_ref, *_):
     return (
-        minfo_ref[desc_ref[n, 0], 0] + desc_ref[n, 1] * TILE_ROWS,
+        _aligned(minfo_ref[desc_ref[n, 0], 0] + desc_ref[n, 1] * TILE_ROWS),
         0,
     )
 
@@ -943,8 +981,11 @@ def _wl_main_consumed(n, desc_ref, minfo_ref, *_):
     return bool(desc_ref[n, 1] * TILE < minfo_ref[desc_ref[n, 0], 1])
 
 
-def _wl_slab_intended(n, desc_ref, minfo_ref, slab_ref, *_):
-    return (slab_ref[desc_ref[n, 0]], 0)
+def _wl_slab_intended(cap_rows):
+    def intended(n, desc_ref, minfo_ref, slab_ref, *_):
+        return (_aligned(slab_ref[desc_ref[n, 0]] * cap_rows), 0)
+
+    return intended
 
 
 def _wl_slab_consumed(n, desc_ref, minfo_ref, slab_ref, len_ref, occ_ref, *_):
@@ -955,7 +996,7 @@ def _wl_packed_window_intended(woff_idx, n_blocks):
     def intended(n, *refs):
         q = refs[0][n, 0]
         b0c = jnp.minimum(refs[1][q, 0] + refs[0][n, 1] * TILE_ROWS, n_blocks)
-        return (refs[woff_idx][b0c] // LANES, 0)
+        return (_aligned(refs[woff_idx][b0c] // LANES), 0)
 
     return intended
 
@@ -965,7 +1006,7 @@ def _wl_packed_slab_intended(woff_idx, bpt, n_blocks):
         q = refs[0][n, 0]
         b0 = jnp.where(refs[4][q] == 0, 0, refs[2][q]) * bpt
         b0c = jnp.minimum(b0, n_blocks)
-        return (refs[woff_idx][b0c] // LANES, 0)
+        return (_aligned(refs[woff_idx][b0c] // LANES), 0)
 
     return intended
 
@@ -976,9 +1017,9 @@ def _build_merge_compact_contract(use_packed):
     # never appear in the table) while query 2's occupied-zero slab keeps
     # the slab-pin clamp + consumed=False escape path exercised in
     # work-list space.
-    arrays, live = synthetic_flat_index((150, 100, 90))
-    delta = synthetic_delta_arrays(3, TILE, fills=(5, 0, 12))
-    n_terms, cap = 3, TILE
+    arrays, live = synthetic_flat_index(_CANON_LISTS)
+    delta = synthetic_delta_arrays(3, _CANON_CAP, fills=(5, 0, 12))
+    n_terms, cap = 3, _CANON_CAP
     bpt = cap // BLOCK
     rows_total = arrays["postings"].shape[0] // LANES
 
@@ -987,7 +1028,7 @@ def _build_merge_compact_contract(use_packed):
     out_rows = s_w * TILE_ROWS
     q_n = 3
     terms = np.array([0, 2, -1], np.int32)
-    m_off = np.array([0, 384, 256], np.int32)
+    m_off = np.array([0, _CANON_OFF[2], _CANON_OFF[1]], np.int32)
     m_neff = np.array([150, 90, 100], np.int32)
     live_q = np.array([True, False, True])
 
@@ -1006,17 +1047,26 @@ def _build_merge_compact_contract(use_packed):
     )
     scalars = (wl.desc, minfo, slab.astype(np.int32), d_len, d_occ)
 
-    tile = (TILE_ROWS, LANES)
+    win = (WINDOW_READ_ROWS, LANES)
     flat_main = (rows_total, LANES)
-    cap_rows = cap // LANES
     flat_delta = (delta["d_postings"].shape[0] // LANES, LANES)
+    cap_rows, _, read_rows, _ = _slab_geometry(cap)
+    slab_blk = (read_rows, LANES)
+    d_map = _wl_slab_map(flat_delta[0], read_rows, cap_rows)
     d_live = int(cap * n_terms)
     main_kw = dict(
-        indexing_mode=UNBLOCKED,
+        indexing_mode=ELEMENT,
         intended_map=_wl_main_window_intended,
         consumed=_wl_main_consumed,
         padding_from=live,
         spare_tile=True,
+        spare_elems=TILE,
+    )
+    slab_kw = dict(
+        indexing_mode=ELEMENT,
+        intended_map=_wl_slab_intended(cap_rows),
+        consumed=_wl_slab_consumed,
+        padding_from=d_live,
     )
     m_map = _wl_main_window_map(rows_total)
     if use_packed:
@@ -1037,7 +1087,7 @@ def _build_merge_compact_contract(use_packed):
             "int32",
             (pk_m.chunk_rows, LANES),
             _wl_packed_window_map(7, pk_m.n_blocks, rows_wm, pk_m.chunk_rows),
-            indexing_mode=UNBLOCKED,
+            indexing_mode=ELEMENT,
             intended_map=_wl_packed_window_intended(7, pk_m.n_blocks),
             consumed=_wl_main_consumed,
             padding_from=int(np.asarray(pk_m.blk_woff)[-1]),
@@ -1051,7 +1101,7 @@ def _build_merge_compact_contract(use_packed):
             _wl_packed_slab_map(
                 10, bpt, pk_d.n_blocks, rows_wd, pk_d.chunk_rows
             ),
-            indexing_mode=UNBLOCKED,
+            indexing_mode=ELEMENT,
             intended_map=_wl_packed_slab_intended(10, bpt, pk_d.n_blocks),
             consumed=_wl_slab_consumed,
             padding_from=int(np.asarray(pk_d.blk_woff)[-1]),
@@ -1059,33 +1109,19 @@ def _build_merge_compact_contract(use_packed):
         )
     else:
         mp_op = OperandContract(
-            "main_postings", flat_main, "int32", tile, m_map, **main_kw
+            "main_postings", flat_main, "int32", win, m_map, **main_kw
         )
         dp_op = OperandContract(
-            "delta_postings",
-            flat_delta,
-            "int32",
-            (cap_rows, LANES),
-            _wl_slab_map,
-            intended_map=_wl_slab_intended,
-            consumed=_wl_slab_consumed,
-            padding_from=d_live,
+            "delta_postings", flat_delta, "int32", slab_blk, d_map, **slab_kw
         )
     ins = (
         mp_op,
         OperandContract(
-            "main_attrs", flat_main, "int32", tile, m_map, **main_kw
+            "main_attrs", flat_main, "int32", win, m_map, **main_kw
         ),
         dp_op,
         OperandContract(
-            "delta_attrs",
-            flat_delta,
-            "int32",
-            (cap_rows, LANES),
-            _wl_slab_map,
-            intended_map=_wl_slab_intended,
-            consumed=_wl_slab_consumed,
-            padding_from=d_live,
+            "delta_attrs", flat_delta, "int32", slab_blk, d_map, **slab_kw
         ),
     )
     blk_o = (1, out_rows, LANES)

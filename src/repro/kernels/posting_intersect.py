@@ -42,6 +42,7 @@ from repro.core.index import (
     TILE,
     PackedFlatArrays,
 )
+from repro.kernels import bitonic
 from repro.kernels.worklist import (
     FLAG_FIRST,
     FLAG_LAST,
@@ -52,6 +53,12 @@ from repro.kernels.worklist import (
 
 TILE_ROWS = 8
 LANES = 128
+#: Rows of an Element-indexed window read: two sublane tiles.  A driver
+#: window starts on a BLOCK (one-row) boundary, but Mosaic only accepts
+#: Element offsets it can prove are multiples of the 8-row sublane tile,
+#: so the kernels read the two aligned tiles covering the TILE_ROWS rows
+#: they need and realign them in VMEM (see :func:`window_read`).
+WINDOW_READ_ROWS = 2 * TILE_ROWS
 # One skippable tile = 1024 postings; the flat arrays are padded to this in
 # core.index, so tile addressing and padding cannot desynchronize.
 assert TILE == TILE_ROWS * LANES
@@ -75,9 +82,11 @@ def _fused_keep(a, a_attr, attr_filter, enabled, live=None) -> jnp.ndarray:
     posting whose document was deleted (or superseded by a delta version)
     arrives with live=0 and dies here.  All fused in one pass — the
     paper's "one sequential scan of the posting list" (Fig 4(b))."""
+    # Boolean algebra, not a select over booleans: Mosaic cannot lower
+    # ``arith.select`` on i1 vectors.
     valid = a != INVALID_DOC
     attr_ok = a_attr == attr_filter
-    keep = valid & jnp.where(enabled, attr_ok, True)
+    keep = valid & (attr_ok | ~enabled)
     if live is not None:
         keep = keep & (live != 0)
     return keep.astype(jnp.int32)
@@ -191,18 +200,40 @@ def _streamed_flat_map(start_idx, n_idx, num_tiles):
     return b_map
 
 
+def window_read(row, rows_total: int):
+    """``(start, shift)`` of the aligned read that covers rows ``[row, row +
+    TILE_ROWS)`` of a ``(rows_total, 128)`` flat array.
+
+    ``start`` is a multiple of TILE_ROWS (provably so for Mosaic: a floor
+    division times the tile) and ``start + WINDOW_READ_ROWS <= rows_total``;
+    the kernel takes ``block[shift:shift + TILE_ROWS]``.  A row in the
+    array's last tile clamps to the read ending at the array edge with
+    ``shift = TILE_ROWS``: it then delivers the spare INVALID tile
+    (:func:`repro.core.index.flat_tile_pad`), and every position it
+    covers is past the live range, which the kernels' position masks
+    discard anyway.
+    """
+    start = (
+        jnp.minimum(row, rows_total - TILE_ROWS - 1) // TILE_ROWS
+    ) * TILE_ROWS
+    return start, jnp.minimum(row - start, TILE_ROWS)
+
+
 def _driver_window_map(rows_total, info_idx):
-    """Unblocked element-row offset of driver tile i: the per-query window
+    """Aligned Element read covering driver tile i: the per-query window
     start rides in prefetch ref ``info_idx`` as ``[row0, n_eff]`` rows."""
 
     def ad_map(q, i, t, j, *refs):
-        # Clamped at the array edge; the spare INVALID tile makes any
-        # clamped tile fully out-of-window, so the kernel's position mask
-        # discards it.
         row = refs[info_idx][q, 0] + i * TILE_ROWS
-        return (jnp.minimum(row, rows_total - TILE_ROWS), 0)
+        return (window_read(row, rows_total)[0], 0)
 
     return ad_map
+
+
+def element_spec(rows, index_map):
+    """``(rows, 128)`` Element read of a flat array at the row offset
+    ``index_map`` returns (a multiple of TILE_ROWS, see :func:`window_read`)."""
+    return pl.BlockSpec((pl.Element(rows), pl.Element(LANES)), index_map)
 
 
 def _driver_out_map(q, i, t, j, *refs):
@@ -217,7 +248,9 @@ def _driver_out_map(q, i, t, j, *refs):
 
 
 def _packed_row0(woff_ref, b0c, rows_w: int, chunk_rows: int):
-    """First word row of the chunk covering block ``b0c``'s packed words.
+    """First word row of the chunk covering block ``b0c``'s packed words:
+    the row holding its first word, rounded down to the sublane tile
+    (``pack_flat_postings`` sizes ``chunk_rows`` from such aligned starts).
 
     The edge clamp mirrors the raw maps' pattern but is provably inert:
     ``packed_word_pad`` keeps >= chunk_rows*BLOCK + TILE zero words past
@@ -225,7 +258,8 @@ def _packed_row0(woff_ref, b0c, rows_w: int, chunk_rows: int):
     every descriptor-clamped ``b0c`` — the packed-space spare-tile
     invariant the contract checker verifies.
     """
-    return jnp.minimum(woff_ref[b0c] // LANES, rows_w - chunk_rows)
+    row = jnp.minimum(woff_ref[b0c] // LANES, rows_w - chunk_rows)
+    return (row // TILE_ROWS) * TILE_ROWS
 
 
 def _decode_block(chunk, base, meta, rel):
@@ -267,7 +301,7 @@ def _decode_block(chunk, base, meta, rel):
     )
     # logical shift: a 32-bit field may have the sign bit set.
     gaps = jax.lax.shift_right_logical(lane_word[None, :], shift) & mask
-    docs = base + jnp.cumsum(gaps, axis=1, dtype=jnp.int32)
+    docs = base + bitonic.lane_prefix_sum(gaps)
     return jnp.where(lane < cnt, docs, INVALID_DOC)
 
 
@@ -916,10 +950,8 @@ def intersect_batched_streamed(
         words_m = packed.words.reshape(-1, LANES)
         pk_m = (packed.n_blocks, words_m.shape[0], packed.chunk_rows)
         in_specs.append(
-            pl.BlockSpec(
-                (packed.chunk_rows, LANES),
-                _packed_flat_map(0, 1, woff_m_idx, *pk_m),
-                indexing_mode=pl.unblocked,
+            element_spec(
+                packed.chunk_rows, _packed_flat_map(0, 1, woff_m_idx, *pk_m)
             )
         )
         operands.append(words_m)
@@ -934,10 +966,9 @@ def intersect_batched_streamed(
             words_d = d_packed.words.reshape(-1, LANES)
             pk_d = (d_packed.n_blocks, words_d.shape[0], d_packed.chunk_rows)
             in_specs.append(
-                pl.BlockSpec(
-                    (d_packed.chunk_rows, LANES),
+                element_spec(
+                    d_packed.chunk_rows,
                     _packed_flat_map(3, 4, woff_d_idx, *pk_d),
-                    indexing_mode=pl.unblocked,
                 )
             )
             operands.append(words_d)
@@ -980,10 +1011,11 @@ def intersect_batched_streamed(
 # the static main index, though, the driver window is just a contiguous
 # BLOCK-aligned slice of the flat posting array — gathering it host-side is
 # pure waste.  This variant reads driver tiles tile-by-tile from the flat
-# ``postings``/``attrs`` arrays through *unblocked-index* BlockSpecs: the
-# per-query window start (off // LANES sublane rows, scalar-prefetched) is
-# an element offset, so a window that begins mid-physical-tile still maps
-# onto clean (8, 128) VMEM reads.  Each driver tile is range-masked to the
+# ``postings``/``attrs`` arrays through Element-indexed BlockSpecs: the
+# per-query window start (off // LANES sublane rows, scalar-prefetched)
+# need not sit on a tile boundary, so each step reads the two aligned
+# tiles covering its driver tile and takes the tile at the window's row
+# shift in VMEM (:func:`window_read`).  Each driver tile is range-masked to the
 # window's live range [0, n_eff) by its *intended* window position; the
 # spare INVALID tile every flat array carries (core.index.flat_tile_pad)
 # guarantees a tile whose read clamps at the array edge is entirely past
@@ -993,7 +1025,9 @@ def intersect_batched_streamed(
 # *output* (the candidate set top-k selects from), never as input staging.
 
 
-def _driver_streamed_kernel(*refs, t_slots: int, s_max: int, packed=None):
+def _driver_streamed_kernel(
+    *refs, t_slots: int, s_max: int, rows_total: int, packed=None
+):
     # Refs (raw mode), in order:
     #   scalar-prefetch (SMEM):
     #     bt_ref     int32[Q, T, num_a]  first overlapping B tile
@@ -1003,8 +1037,8 @@ def _driver_streamed_kernel(*refs, t_slots: int, s_max: int, packed=None):
     #     attr_ref   int32[Q, 2]         [attr_filter, attr_enabled]
     #     ainfo_ref  int32[Q, 2]         [driver row0, driver n_eff]
     #   VMEM:
-    #     ad_ref     (8,128) driver docID tile (unblocked stream)
-    #     aa_ref     (8,128) driver attr tile (unblocked stream)
+    #     ad_ref     (16,128) aligned read covering driver tile i's docIDs
+    #     aa_ref     (16,128) the same read of the attrs
     #     pm_ref     (8,128) current other-term tile
     #   outputs:
     #     outd_ref   (1,8,128) driver docIDs (window-aligned, INVALID past n_eff)
@@ -1029,6 +1063,7 @@ def _driver_streamed_kernel(*refs, t_slots: int, s_max: int, packed=None):
     i = pl.program_id(1)
     t = pl.program_id(2)
     j = pl.program_id(3)
+    _, shift = window_read(ainfo_ref[q, 0] + i * TILE_ROWS, rows_total)
 
     if packed is not None:
         # Decode the driver tile once per (q, i) — (t, j) = (0, 0) is the
@@ -1045,16 +1080,16 @@ def _driver_streamed_kernel(*refs, t_slots: int, s_max: int, packed=None):
                 b0c, row0, TILE_ROWS,
             )
 
-        a_src = adk_ref
+        a_tile = adk_ref[...]
     else:
-        a_src = ad_ref
+        a_tile = ad_ref[pl.ds(shift, TILE_ROWS), :]
 
     # The driver tile, masked by *intended* window position: slots at or
     # past n_eff read INVALID no matter what the (possibly clamped) DMA
     # delivered.  Tiles are window-aligned, so tile i holds window
     # positions [i*TILE, (i+1)*TILE).
     in_win = _tile_positions(i) < ainfo_ref[q, 1]
-    a = jnp.where(in_win, a_src[...], INVALID_DOC)
+    a = jnp.where(in_win, a_tile, INVALID_DOC)
 
     @pl.when((t == 0) & (j == 0))
     def _init_out():
@@ -1092,7 +1127,9 @@ def _driver_streamed_kernel(*refs, t_slots: int, s_max: int, packed=None):
 
     @pl.when((t == t_slots - 1) & (j == s_max - 1))
     def _finalize():
-        aa = jnp.where(in_win, aa_ref[...], INVALID_ATTR)
+        aa = jnp.where(
+            in_win, aa_ref[pl.ds(shift, TILE_ROWS), :], INVALID_ATTR
+        )
         keep = _fused_keep(a, aa, attr_ref[q, 0], attr_ref[q, 1] != 0)
         outm_ref[0] = outm_ref[0] * keep
 
@@ -1119,7 +1156,7 @@ def intersect_batched_driver_streamed(
     remaining host-side materialization: instead of a gathered ``(Q, W)``
     driver operand, per-query driver tile offsets (``d_off``/``d_neff``,
     supplied by the engine's PostingSource layer) are scalar-prefetched and
-    unblocked-index BlockSpecs walk the flat ``postings``/``attrs`` arrays
+    Element-indexed BlockSpecs walk the flat ``postings``/``attrs`` arrays
     directly.  Driver-tile docID spans for the other-term probe plan come
     from the BLOCK skip table (:func:`driver_tile_spans`) — conservative,
     never lossy.
@@ -1170,25 +1207,18 @@ def intersect_batched_driver_streamed(
         scalars += [packed.blk_base, packed.blk_meta, packed.blk_woff]
         words_m = packed.words.reshape(-1, LANES)
         pk = (packed.n_blocks, words_m.shape[0], packed.chunk_rows)
-        chunk = (packed.chunk_rows, LANES)
         in_specs = [
-            pl.BlockSpec(
-                chunk, _packed_driver_map(5, 8, *pk),
-                indexing_mode=pl.unblocked,
-            ),
-            pl.BlockSpec((TILE_ROWS, LANES), ad_map, indexing_mode=pl.unblocked),
-            pl.BlockSpec(
-                chunk, _packed_flat_map(0, 1, 8, *pk),
-                indexing_mode=pl.unblocked,
-            ),
+            element_spec(packed.chunk_rows, _packed_driver_map(5, 8, *pk)),
+            element_spec(WINDOW_READ_ROWS, ad_map),
+            element_spec(packed.chunk_rows, _packed_flat_map(0, 1, 8, *pk)),
         ]
         operands = [words_m, pa2, words_m]
         scratch.append(pltpu.VMEM((TILE_ROWS, LANES), jnp.int32))
     else:
         pk = None
         in_specs = [
-            pl.BlockSpec((TILE_ROWS, LANES), ad_map, indexing_mode=pl.unblocked),
-            pl.BlockSpec((TILE_ROWS, LANES), ad_map, indexing_mode=pl.unblocked),
+            element_spec(WINDOW_READ_ROWS, ad_map),
+            element_spec(WINDOW_READ_ROWS, ad_map),
             pl.BlockSpec((TILE_ROWS, LANES), b_map),
         ]
         operands = [pm2, pa2, pm2]
@@ -1207,7 +1237,7 @@ def intersect_batched_driver_streamed(
     docs, mask = pl.pallas_call(
         functools.partial(
             _driver_streamed_kernel, t_slots=t_slots, s_max=s_grid,
-            packed=pk,
+            rows_total=rows_total, packed=pk,
         ),
         grid_spec=grid_spec,
         out_shape=[shape, shape],
@@ -1269,15 +1299,15 @@ def _wl_packed_probe_map(field, woff_idx, n_blocks, rows_w, chunk_rows):
 
 
 def _wl_driver_window_map(rows_total, info_idx):
-    """Unblocked driver-window map in work-list space: row0 of window tile
-    ``desc[n, 1]`` of query ``desc[n, 0]``, edge-clamped like
+    """Driver-window map in work-list space: the aligned read covering
+    window tile ``desc[n, 1]`` of query ``desc[n, 0]``, edge-clamped like
     :func:`_driver_window_map` (safe iff the spare tile exists and the
     kernel's ``in_win`` mask discards the clamped slots)."""
 
     def ad_map(n, *refs):
         q = refs[0][n, 0]
         row = refs[info_idx][q, 0] + refs[0][n, 1] * TILE_ROWS
-        return (jnp.minimum(row, rows_total - TILE_ROWS), 0)
+        return (window_read(row, rows_total)[0], 0)
 
     return ad_map
 
@@ -1460,11 +1490,7 @@ def _streamed_compact_call(
     in_specs = [blk_a for _ in operands]
     if use_packed:
         in_specs.append(
-            pl.BlockSpec(
-                (pk_m[2], LANES),
-                _wl_packed_probe_map(3, woff_m_idx, *pk_m),
-                indexing_mode=pl.unblocked,
-            )
+            element_spec(pk_m[2], _wl_packed_probe_map(3, woff_m_idx, *pk_m))
         )
     else:
         num_m = stream_m.shape[0] // TILE_ROWS
@@ -1476,10 +1502,8 @@ def _streamed_compact_call(
     if has_delta:
         if use_packed:
             in_specs.append(
-                pl.BlockSpec(
-                    (pk_d[2], LANES),
-                    _wl_packed_probe_map(5, woff_d_idx, *pk_d),
-                    indexing_mode=pl.unblocked,
+                element_spec(
+                    pk_d[2], _wl_packed_probe_map(5, woff_d_idx, *pk_d)
                 )
             )
         else:
@@ -1617,7 +1641,7 @@ def intersect_batched_streamed_compact(
     )
 
 
-def _driver_compact_kernel(*refs, packed=None):
+def _driver_compact_kernel(*refs, rows_total: int, packed=None):
     # Work-list twin of _driver_streamed_kernel.  Scalar-prefetch order:
     # wl, bounds, attr, a_info, [packed descriptors]; the flags replace the
     # (t, j) edge tests and ``active`` is implicit in item existence.
@@ -1635,6 +1659,7 @@ def _driver_compact_kernel(*refs, packed=None):
     i = wl_ref[n, 1]
     t = wl_ref[n, 2]
     flags = wl_ref[n, 4]
+    _, shift = window_read(ainfo_ref[q, 0] + i * TILE_ROWS, rows_total)
 
     if packed is not None:
         # Decode the driver tile on the group's first item; the scratch
@@ -1648,12 +1673,12 @@ def _driver_compact_kernel(*refs, packed=None):
                 b0c, row0, TILE_ROWS,
             )
 
-        a_src = adk_ref
+        a_tile = adk_ref[...]
     else:
-        a_src = ad_ref
+        a_tile = ad_ref[pl.ds(shift, TILE_ROWS), :]
 
     in_win = _tile_positions(i) < ainfo_ref[q, 1]
-    a = jnp.where(in_win, a_src[...], INVALID_DOC)
+    a = jnp.where(in_win, a_tile, INVALID_DOC)
 
     @pl.when((flags & FLAG_FIRST) != 0)
     def _init_out():
@@ -1689,7 +1714,9 @@ def _driver_compact_kernel(*refs, packed=None):
 
     @pl.when((flags & FLAG_LAST) != 0)
     def _finalize():
-        aa = jnp.where(in_win, aa_ref[...], INVALID_ATTR)
+        aa = jnp.where(
+            in_win, aa_ref[pl.ds(shift, TILE_ROWS), :], INVALID_ATTR
+        )
         keep = _fused_keep(a, aa, attr_ref[q, 0], attr_ref[q, 1] != 0)
         outm_ref[0] = outm_ref[0] * keep
 
@@ -1728,24 +1755,17 @@ def _driver_compact_call(
     ad_map = _wl_driver_window_map(rows_total, 3)
     if pk is not None:
         scalars += list(pdesc)
-        chunk = (pk[2], LANES)
         in_specs = [
-            pl.BlockSpec(
-                chunk, _wl_packed_driver_map(3, 6, *pk),
-                indexing_mode=pl.unblocked,
-            ),
-            pl.BlockSpec((TILE_ROWS, LANES), ad_map, indexing_mode=pl.unblocked),
-            pl.BlockSpec(
-                chunk, _wl_packed_probe_map(3, 6, *pk),
-                indexing_mode=pl.unblocked,
-            ),
+            element_spec(pk[2], _wl_packed_driver_map(3, 6, *pk)),
+            element_spec(WINDOW_READ_ROWS, ad_map),
+            element_spec(pk[2], _wl_packed_probe_map(3, 6, *pk)),
         ]
         scratch.append(pltpu.VMEM((TILE_ROWS, LANES), jnp.int32))
     else:
         num_m = stream_b.shape[0] // TILE_ROWS
         in_specs = [
-            pl.BlockSpec((TILE_ROWS, LANES), ad_map, indexing_mode=pl.unblocked),
-            pl.BlockSpec((TILE_ROWS, LANES), ad_map, indexing_mode=pl.unblocked),
+            element_spec(WINDOW_READ_ROWS, ad_map),
+            element_spec(WINDOW_READ_ROWS, ad_map),
             pl.BlockSpec((TILE_ROWS, LANES), _wl_probe_map(3, num_m)),
         ]
     operands = [stream_a, pa2, stream_b]
@@ -1760,7 +1780,9 @@ def _driver_compact_call(
     )
     shape = jax.ShapeDtypeStruct((q_n, num_a * TILE_ROWS, LANES), jnp.int32)
     docs, mask = pl.pallas_call(
-        functools.partial(_driver_compact_kernel, packed=pk),
+        functools.partial(
+            _driver_compact_kernel, rows_total=rows_total, packed=pk
+        ),
         grid_spec=grid_spec,
         out_shape=[shape, shape],
         interpret=interpret,
@@ -1871,7 +1893,7 @@ def skip_fraction(a_docs: jnp.ndarray, b_docs: jnp.ndarray) -> jnp.ndarray:
 # so the edge-clamp path — the PR 5 bug class — is exercised by contract.
 
 from repro.kernels.registry import (  # noqa: E402
-    UNBLOCKED,
+    ELEMENT,
     KernelContract,
     OperandContract,
     kernel_contract,
@@ -1879,18 +1901,26 @@ from repro.kernels.registry import (  # noqa: E402
     synthetic_flat_index,
 )
 
-# Canonical list lengths: 150 (2 blocks) + 100 + 90 postings -> live extent
-# 512, flat arrays flat_tile_pad'ed to 2048.  The last list (term 2) ends
-# mid-tile at the array edge: streaming its window forces the unblocked
-# read clamp that only the spare INVALID tile makes safe.
-_CANON_LISTS = (150, 100, 90)
+# Canonical list lengths: 1100 (9 blocks) + 100 + 90 postings -> lists at
+# flat offsets 0, 1152 and 1280, live extent 1408 (11 rows), flat arrays
+# flat_tile_pad'ed to 3072 (24 rows).  The last list (term 2) starts in
+# the second tile and ends mid-tile next to the spare tile: streaming its
+# window forces the window-read clamp that only the spare INVALID tile
+# makes safe.  Under the historical floor+1 pad (2048 = 16 rows) the same
+# clamp lands on its live postings, which the checker must catch.
+_CANON_LISTS = (1100, 100, 90)
+_CANON_OFF = (0, 1152, 1280)
+
+
+def _aligned(row):
+    return (row // TILE_ROWS) * TILE_ROWS
 
 
 def _driver_window_intended(info_idx):
     """Pre-clamp address of :func:`_driver_window_map` — contract only."""
 
     def ad_map(q, i, t, j, *refs):
-        return (refs[info_idx][q, 0] + i * TILE_ROWS, 0)
+        return (_aligned(refs[info_idx][q, 0] + i * TILE_ROWS), 0)
 
     return ad_map
 
@@ -1924,7 +1954,7 @@ def _packed_flat_intended(start_idx, woff_idx, n_blocks):
         b0c = jnp.minimum(
             (refs[start_idx][q, t, i] + j) * (TILE // BLOCK), n_blocks
         )
-        return (refs[woff_idx][b0c] // LANES, 0)
+        return (_aligned(refs[woff_idx][b0c] // LANES), 0)
 
     return b_map
 
@@ -1937,7 +1967,7 @@ def _packed_driver_intended(info_idx, woff_idx, n_blocks):
         b0c = jnp.minimum(
             refs[info_idx][q, 0] + i * (TILE // BLOCK), n_blocks
         )
-        return (refs[woff_idx][b0c] // LANES, 0)
+        return (_aligned(refs[woff_idx][b0c] // LANES), 0)
 
     return ad_map
 
@@ -1958,7 +1988,7 @@ def _packed_stream_op(
         _packed_flat_map(
             start_idx, n_idx, woff_idx, pk.n_blocks, rows_w, pk.chunk_rows
         ),
-        indexing_mode=UNBLOCKED,
+        indexing_mode=ELEMENT,
         intended_map=_packed_flat_intended(start_idx, woff_idx, pk.n_blocks),
         consumed=_streamed_flat_consumed(n_idx),
         padding_from=live_words,
@@ -2033,15 +2063,15 @@ def _contract_intersect_batched():
     a = np.stack(
         [
             _host_window(postings, 0, 150, window, INVALID_DOC),
-            _host_window(postings, 384, 90, window, INVALID_DOC),
+            _host_window(postings, _CANON_OFF[2], 90, window, INVALID_DOC),
         ]
     )
     b = np.stack(
         [
             np.stack(
                 [
-                    _host_window(postings, 256, 100, 2 * TILE, INVALID_DOC),
-                    _host_window(postings, 384, 90, 2 * TILE, INVALID_DOC),
+                    _host_window(postings, _CANON_OFF[1], 100, 2 * TILE, INVALID_DOC),
+                    _host_window(postings, _CANON_OFF[2], 90, 2 * TILE, INVALID_DOC),
                 ]
             ),
             np.stack(
@@ -2121,7 +2151,7 @@ def _build_streamed_contract(use_packed: bool) -> KernelContract:
     a = np.stack(
         [
             _host_window(postings, 0, 150, window, INVALID_DOC),
-            _host_window(postings, 384, 90, window, INVALID_DOC),
+            _host_window(postings, _CANON_OFF[2], 90, window, INVALID_DOC),
         ]
     )
     num_a = 1
@@ -2256,7 +2286,7 @@ def _build_driver_streamed_contract(use_packed: bool) -> KernelContract:
     # window > live extent of the edge list: driver tile 1 of query 1 reads
     # past the array end and clamps — safe iff the spare tile exists.
     q_n, t_slots, window = 2, 2, 2 * TILE
-    d_off = np.array([0, 384], np.int32)       # term 0, term 2 (edge list)
+    d_off = np.array([0, _CANON_OFF[2]], np.int32)       # term 0, term 2 (edge list)
     d_neff = np.array([150, 90], np.int32)
     terms = np.array([[1, 2], [0, -1]], np.int32)
     active = np.array([[1, 1], [1, 0]], np.int32)
@@ -2293,14 +2323,16 @@ def _build_driver_streamed_contract(use_packed: bool) -> KernelContract:
         return bool(i * TILE < refs[5][q, 1])
 
     tile = (TILE_ROWS, LANES)
+    win = (WINDOW_READ_ROWS, LANES)
     flat_shape = (rows_total, LANES)
     out_shape = (q_n, num_a * TILE_ROWS, LANES)
     stream_kw = dict(
-        indexing_mode=UNBLOCKED,
+        indexing_mode=ELEMENT,
         intended_map=_driver_window_intended(5),
         consumed=ad_consumed,
         padding_from=live,
         spare_tile=True,
+        spare_elems=TILE,
     )
     if use_packed:
         pk = pack_flat_postings(arrays["postings"])
@@ -2318,7 +2350,7 @@ def _build_driver_streamed_contract(use_packed: bool) -> KernelContract:
                 "int32",
                 (pk.chunk_rows, LANES),
                 _packed_driver_map(5, 8, pk.n_blocks, rows_w, pk.chunk_rows),
-                indexing_mode=UNBLOCKED,
+                indexing_mode=ELEMENT,
                 intended_map=_packed_driver_intended(5, 8, pk.n_blocks),
                 consumed=ad_consumed,
                 padding_from=live_words,
@@ -2328,7 +2360,7 @@ def _build_driver_streamed_contract(use_packed: bool) -> KernelContract:
                 "attrs(driver)",
                 flat_shape,
                 "int32",
-                tile,
+                win,
                 _driver_window_map(rows_total, 5),
                 **stream_kw,
             ),
@@ -2340,7 +2372,7 @@ def _build_driver_streamed_contract(use_packed: bool) -> KernelContract:
                 "postings(driver)",
                 flat_shape,
                 "int32",
-                tile,
+                win,
                 _driver_window_map(rows_total, 5),
                 **stream_kw,
             ),
@@ -2348,7 +2380,7 @@ def _build_driver_streamed_contract(use_packed: bool) -> KernelContract:
                 "attrs(driver)",
                 flat_shape,
                 "int32",
-                tile,
+                win,
                 _driver_window_map(rows_total, 5),
                 **stream_kw,
             ),
@@ -2380,7 +2412,7 @@ def _build_driver_streamed_contract(use_packed: bool) -> KernelContract:
         ),
         scratch=tuple(scratch),
         revisit_dims=(2, 3),
-        notes="fully-streamed read path: unblocked driver window stream"
+        notes="fully-streamed read path: aligned driver window reads"
         + (", block-codec posting streams" if use_packed else ""),
     )
 
@@ -2430,7 +2462,7 @@ def _wl_packed_probe_intended(field, woff_idx, n_blocks):
     def b_map(n, *refs):
         tile = jnp.maximum(refs[0][n, field], 0)
         b0c = jnp.minimum(tile * (TILE // BLOCK), n_blocks)
-        return (refs[woff_idx][b0c] // LANES, 0)
+        return (_aligned(refs[woff_idx][b0c] // LANES), 0)
 
     return b_map
 
@@ -2438,7 +2470,7 @@ def _wl_packed_probe_intended(field, woff_idx, n_blocks):
 def _wl_driver_window_intended(info_idx):
     def ad_map(n, *refs):
         q = refs[0][n, 0]
-        return (refs[info_idx][q, 0] + refs[0][n, 1] * TILE_ROWS, 0)
+        return (_aligned(refs[info_idx][q, 0] + refs[0][n, 1] * TILE_ROWS), 0)
 
     return ad_map
 
@@ -2457,7 +2489,7 @@ def _wl_packed_driver_intended(info_idx, woff_idx, n_blocks):
         b0c = jnp.minimum(
             refs[info_idx][q, 0] + refs[0][n, 1] * (TILE // BLOCK), n_blocks
         )
-        return (refs[woff_idx][b0c] // LANES, 0)
+        return (_aligned(refs[woff_idx][b0c] // LANES), 0)
 
     return ad_map
 
@@ -2473,7 +2505,7 @@ def _wl_packed_stream_op(name, pk, field, woff_idx) -> "OperandContract":
         _wl_packed_probe_map(
             field, woff_idx, pk.n_blocks, rows_w, pk.chunk_rows
         ),
-        indexing_mode=UNBLOCKED,
+        indexing_mode=ELEMENT,
         intended_map=_wl_packed_probe_intended(field, woff_idx, pk.n_blocks),
         consumed=_wl_field_consumed(field),
         padding_from=live_words,
@@ -2498,7 +2530,7 @@ def _build_streamed_compact_contract(use_packed: bool) -> KernelContract:
     a = np.stack(
         [
             _host_window(postings, 0, 150, window, INVALID_DOC),
-            _host_window(postings, 384, 90, window, INVALID_DOC),
+            _host_window(postings, _CANON_OFF[2], 90, window, INVALID_DOC),
         ]
     )
     num_a = 1
@@ -2634,7 +2666,7 @@ def _build_driver_compact_contract(use_packed: bool) -> KernelContract:
     # Same canonical instance as the dense driver-streamed contract: the
     # edge list's second window tile still forces the clamp path.
     q_n, t_slots, window = 2, 2, 2 * TILE
-    d_off = np.array([0, 384], np.int32)
+    d_off = np.array([0, _CANON_OFF[2]], np.int32)
     d_neff = np.array([150, 90], np.int32)
     terms = np.array([[1, 2], [0, -1]], np.int32)
     active = np.array([[1, 1], [1, 0]], np.int32)
@@ -2670,14 +2702,16 @@ def _build_driver_compact_contract(use_packed: bool) -> KernelContract:
     ]
 
     tile = (TILE_ROWS, LANES)
+    win = (WINDOW_READ_ROWS, LANES)
     flat_shape = (rows_total, LANES)
     out_shape = (q_n, num_a * TILE_ROWS, LANES)
     stream_kw = dict(
-        indexing_mode=UNBLOCKED,
+        indexing_mode=ELEMENT,
         intended_map=_wl_driver_window_intended(3),
         consumed=_wl_driver_consumed(3),
         padding_from=live,
         spare_tile=True,
+        spare_elems=TILE,
     )
     if use_packed:
         pk = pack_flat_postings(arrays["postings"])
@@ -2695,7 +2729,7 @@ def _build_driver_compact_contract(use_packed: bool) -> KernelContract:
                 "int32",
                 (pk.chunk_rows, LANES),
                 _wl_packed_driver_map(3, 6, pk.n_blocks, rows_w, pk.chunk_rows),
-                indexing_mode=UNBLOCKED,
+                indexing_mode=ELEMENT,
                 intended_map=_wl_packed_driver_intended(3, 6, pk.n_blocks),
                 consumed=_wl_driver_consumed(3),
                 padding_from=live_words,
@@ -2705,7 +2739,7 @@ def _build_driver_compact_contract(use_packed: bool) -> KernelContract:
                 "attrs(driver)",
                 flat_shape,
                 "int32",
-                tile,
+                win,
                 _wl_driver_window_map(rows_total, 3),
                 **stream_kw,
             ),
@@ -2717,7 +2751,7 @@ def _build_driver_compact_contract(use_packed: bool) -> KernelContract:
                 "postings(driver)",
                 flat_shape,
                 "int32",
-                tile,
+                win,
                 _wl_driver_window_map(rows_total, 3),
                 **stream_kw,
             ),
@@ -2725,7 +2759,7 @@ def _build_driver_compact_contract(use_packed: bool) -> KernelContract:
                 "attrs(driver)",
                 flat_shape,
                 "int32",
-                tile,
+                win,
                 _wl_driver_window_map(rows_total, 3),
                 **stream_kw,
             ),

@@ -2,7 +2,9 @@
 
 The streamed read path (ROADMAP "Fully-streamed read path") made kernel
 correctness hinge on invariants that no runtime test can see until they
-bite: unblocked-index BlockSpecs must stay inside the spare INVALID tile
+bite: Element-indexed BlockSpecs must start on a sublane-tile boundary
+(Mosaic refuses any offset it cannot prove aligned) and their edge
+clamps must land inside the spare INVALID tile
 that :func:`repro.core.index.flat_tile_pad` guarantees, scalar-prefetched
 index maps must never alias two grid steps onto one output block, and
 VMEM residency must fit real hardware budgets that ``interpret=True``
@@ -42,7 +44,9 @@ from typing import Callable, Sequence
 import numpy as np
 
 BLOCKED = "blocked"
-UNBLOCKED = "unblocked"
+#: ``pl.Element`` dims: the index map returns element offsets, not block
+#: indices.  Row offsets must be multiples of the dtype's sublane tile.
+ELEMENT = "element"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,9 +70,13 @@ class OperandContract:
     # Flat live extent: every element at offset >= padding_from (in the
     # flattened array) is guaranteed INVALID fill.
     padding_from: int | None = None
-    # Require a full spare block of padding past ``padding_from`` — the
-    # flat_tile_pad invariant an edge-clamped unblocked read relies on.
+    # Require a spare block of padding past ``padding_from`` — the
+    # flat_tile_pad invariant an edge-clamped Element read relies on.
     spare_tile: bool = False
+    # Elements of the spare region ``spare_tile`` demands; None = the whole
+    # block.  A window read that keeps only one TILE of a two-tile block
+    # needs only that TILE past the live extent.
+    spare_elems: int | None = None
 
     @property
     def block_elems(self) -> int:

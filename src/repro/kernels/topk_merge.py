@@ -8,11 +8,11 @@ exact opposite of what a VPU wants.  The collective-native equivalent of a
 tournament is a **bitonic sorting network**: O(log² n) *data-independent*
 compare-exchange stages, each a dense vector min/max over the whole array.
 We sort the concatenated (ns·k) candidate docIDs ascending (docID == rank,
-DESIGN.md §2) and take the first k.  Every stage with XOR-distance d is
-expressed as a reshape to (n/2d, 2, d) + elementwise min/max — no gathers,
-no branches; sub-lane stages (d < 128) become relayouts, which XLA/Mosaic
-handle (a production kernel would swap register shuffles in; semantics are
-identical).
+DESIGN.md §2) and take the first k.  The network runs on the
+(rows, 128) tile as it sits in VMEM: each stage fetches XOR partners with
+lane or sublane rotates and keeps min or max (:mod:`repro.kernels.bitonic`)
+— no gathers, no branches, no flat reshapes.  Candidates pad to at least
+one (8, 128) tile so every block is sublane-tile aligned.
 """
 from __future__ import annotations
 
@@ -22,34 +22,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.index import INVALID_DOC
-
-
-def _bitonic_sort_flat(x: jnp.ndarray) -> jnp.ndarray:
-    """Ascending bitonic sort of a flat power-of-two-length vector."""
-    n = x.shape[0]
-    log_n = n.bit_length() - 1
-    assert 1 << log_n == n, "bitonic sort needs power-of-two length"
-    for k in range(1, log_n + 1):          # merge size 2^k
-        for j in range(k - 1, -1, -1):     # XOR distance 2^j
-            d = 1 << j
-            blocks = n // (2 * d)
-            y = x.reshape(blocks, 2, d)
-            lo, hi = y[:, 0, :], y[:, 1, :]
-            # descending iff bit k of the element index is set; for block b
-            # that is bit (k-j-1) of b.
-            desc = ((jnp.arange(blocks, dtype=jnp.int32) >> (k - j - 1)) & 1) == 1
-            desc = desc[:, None]
-            mn = jnp.minimum(lo, hi)
-            mx = jnp.maximum(lo, hi)
-            new_lo = jnp.where(desc, mx, mn)
-            new_hi = jnp.where(desc, mn, mx)
-            x = jnp.stack([new_lo, new_hi], axis=1).reshape(n)
-    return x
+from repro.core.index import INVALID_DOC, TILE
+from repro.kernels import bitonic
 
 
 def _sort_kernel(x_ref, o_ref):
-    o_ref[...] = _bitonic_sort_flat(x_ref[...].reshape(-1)).reshape(o_ref.shape)
+    o_ref[...] = bitonic.sort(x_ref[...].reshape(o_ref.shape[-2:])).reshape(
+        o_ref.shape
+    )
 
 
 def _next_pow2(n: int) -> int:
@@ -73,7 +53,7 @@ def _row_map(i):
 def bitonic_sort(x: jnp.ndarray, *, interpret: bool = False) -> jnp.ndarray:
     """Ascending sort via the Pallas bitonic kernel (pads to pow2/lanes)."""
     n = x.shape[0]
-    m = max(256, _next_pow2(n))  # >=2 lane rows keeps the layout 2D-friendly
+    m = max(TILE, _next_pow2(n))  # >= one (8, 128) tile
     xp = jnp.pad(x, (0, m - n), constant_values=INVALID_DOC)
     rows = m // 128
     out = pl.pallas_call(
@@ -116,11 +96,11 @@ def merge_topk_rows(
     engine runs under ``backend="pallas"``.
     """
     q_n, m = cands.shape
-    mpad = max(256, _next_pow2(m))  # >=2 lane rows keeps the layout 2D-friendly
+    mpad = max(TILE, _next_pow2(m))  # >= one (8, 128) tile
     rows = mpad // 128
     xp = jnp.pad(cands, ((0, 0), (0, mpad - m)), constant_values=INVALID_DOC)
     out = pl.pallas_call(
-        _sort_kernel,  # grid block (1, rows, 128): same flatten-sort body
+        _sort_kernel,  # grid block (1, rows, 128): same sort body
         grid=(q_n,),
         out_shape=jax.ShapeDtypeStruct((q_n, rows, 128), cands.dtype),
         in_specs=[pl.BlockSpec((1, rows, 128), _row_map)],
@@ -145,7 +125,7 @@ from repro.kernels.registry import (  # noqa: E402
 @kernel_contract("bitonic_sort")
 def _contract_bitonic_sort():
     # Canonical: n = 2048 candidates -> one (16, 128) block, no grid.
-    rows = max(256, _next_pow2(2048)) // 128
+    rows = max(TILE, _next_pow2(2048)) // 128
     shape = (rows, 128)
     return KernelContract(
         name="bitonic_sort",
@@ -161,7 +141,7 @@ def _contract_bitonic_sort():
 def _contract_merge_topk_rows():
     # Canonical: Q = 4 queries, m = 1024 candidates per row.
     q_n = 4
-    rows = max(256, _next_pow2(1024)) // 128
+    rows = max(TILE, _next_pow2(1024)) // 128
     shape = (q_n, rows, 128)
     blk = (1, rows, 128)
     return KernelContract(

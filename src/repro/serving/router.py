@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.compat import shard_map
 from repro.core.faults import SetHealth
 from repro.serving.scheduler import MultiSetRouter, SetState
 
@@ -137,7 +136,7 @@ def distributed_vocab_topk(
     n = mesh.shape[axis]
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=P(batch_axes, axis),
         out_specs=(P(batch_axes, None), P(batch_axes, None)),

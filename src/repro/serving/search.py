@@ -144,6 +144,7 @@ class SearchService:
         self.index = index
         self.meta = meta
         self.mesh = mesh
+        self._place_index()
         self.ns = ns
         self.k = k
         self.window = window
@@ -177,7 +178,8 @@ class SearchService:
             raise ValueError(f"t_max_buckets {buckets} exceed t_max={t_max}")
         self.set_meshes = list(set_meshes) if set_meshes is not None else None
         self._set_index: list[ShardedIndex] | None = None
-        self._set_delta: dict[int, tuple[object, object]] = {}
+        # delta placements per set slice; key None = the service mesh
+        self._set_delta: dict[int | None, tuple[object, object]] = {}
         if self.set_meshes is not None:
             if len(self.set_meshes) != n_sets:
                 raise ValueError(
@@ -255,6 +257,7 @@ class SearchService:
             writer, verify=verify,
             term_capacity=term_capacity, doc_headroom=doc_headroom,
         )
+        self._place_index()
         if self.set_meshes is not None:
             # the main index changed identity: every slice re-places it
             # (the per-set delta cache is cleared there too — the rebase
@@ -284,6 +287,14 @@ class SearchService:
         extra = 1 if (site is not None and self.strategy == "site_term") else 0
         return len(terms) + extra
 
+    def _place_index(self) -> None:
+        """Lay the main index out over the service mesh's ``data`` axis,
+        one shard per device, once — not copied from the default device
+        at every dispatch."""
+        self.index = jax.device_put(
+            self.index, NamedSharding(self.mesh, P("data"))
+        )
+
     def _place_set_indexes(self) -> None:
         """(Re)place the main index on every set's mesh slice.
 
@@ -297,9 +308,10 @@ class SearchService:
         ]
         self._set_delta.clear()
 
-    def _set_delta_snapshot(self, set_id: int):
-        """Current delta snapshot placed on ``set_id``'s slice, cached per
-        (set, writer version) — a new publish on any shard re-places."""
+    def _delta_snapshot(self, set_id: int | None = None):
+        """Current delta snapshot placed on ``set_id``'s slice (None: the
+        service mesh), cached per (placement, writer version) — a new
+        publish on any shard re-places."""
         if self.writer is None:
             return None
         snap = self.writer.device_delta()
@@ -307,9 +319,8 @@ class SearchService:
         cached = self._set_delta.get(set_id)
         if cached is not None and cached[0] == ver:
             return cached[1]
-        placed = jax.device_put(
-            snap, NamedSharding(self.set_meshes[set_id], P("data"))
-        )
+        mesh = self.mesh if set_id is None else self.set_meshes[set_id]
+        placed = jax.device_put(snap, NamedSharding(mesh, P("data")))
         self._set_delta[set_id] = (ver, placed)
         return placed
 
@@ -328,7 +339,7 @@ class SearchService:
             return replicated_query_topk(
                 self._set_index[set_id],
                 batch,
-                self._set_delta_snapshot(set_id),
+                self._delta_snapshot(set_id),
                 mesh=self.set_meshes[set_id],
                 ns=self.ns,
                 k=k,
@@ -338,11 +349,10 @@ class SearchService:
                 backend=self.backend,
                 interpret=self.interpret,
             )
-        delta = None if self.writer is None else self.writer.device_delta()
         return distributed_query_topk(
             self.index,
             batch,
-            delta,
+            self._delta_snapshot(),
             mesh=self.mesh,
             ns=self.ns,
             k=k,

@@ -138,7 +138,7 @@ def test_empty_lists_and_all_pad_tiles(window):
 def test_driver_stream_at_array_edge(with_delta):
     """Spare-tile invariant regression (flat_tile_pad must be ceil+1, not
     floor+1): a driver list that starts inside the flat array's final
-    partial tile forces the unblocked window read to clamp at the array
+    partial tile forces the Element window read to clamp at the array
     edge.  Without a whole spare INVALID tile past the last posting, the
     clamped read serves the *previous* list's postings into in-window
     slots and the streamed backend returns documents of the wrong term."""

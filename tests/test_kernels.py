@@ -160,3 +160,22 @@ def test_skip_fraction_increases_with_disjointness():
     high = float(ops.skip_fraction(a, b_far))
     assert high > 0.9
     assert high > low
+
+
+@pytest.mark.parametrize(
+    "backend,interpret,packed,want",
+    [
+        ("cpu", None, False, True),
+        ("cpu", None, True, True),         # the interpreter runs packed
+        ("tpu", None, False, False),       # compiled on the chip
+        ("tpu", True, False, ValueError),  # never interpret on the chip
+        ("tpu", None, True, NotImplementedError),
+    ],
+)
+def test_resolve_interpret(monkeypatch, backend, interpret, packed, want):
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    if isinstance(want, bool):
+        assert ops.resolve_interpret(interpret, packed=packed) is want
+    else:
+        with pytest.raises(want):
+            ops.resolve_interpret(interpret, packed=packed)
