@@ -103,7 +103,6 @@ from repro.core.index import (
     unpack_flat_postings_jnp,
 )
 from repro.indexing.delta import DOC_DEAD, DOC_SUPERSEDED, DeltaIndex
-from repro.obs.registry import get_registry
 
 NO_TERM = np.int32(-1)
 NO_ATTR = np.int32(-1)
@@ -133,19 +132,9 @@ def make_query_batch(
     With ``strategy='site_term'`` the site restriction is rewritten into an
     extra join term (Fig 1(d)) and ``attr_filter`` stays empty.
 
-    This runs host-side (unlike the jitted query program, which must not
-    carry runtime instrumentation — its Python only executes at trace
-    time), so it is where the engine's batch-construction counters live.
+    This runs host-side, ahead of the jitted query program; the serving
+    path times it as the batch's ``batch_build`` phase.
     """
-    reg = get_registry()
-    reg.counter(
-        "odys_engine_batches_built_total",
-        help="query batches constructed for the device",
-    ).inc()
-    reg.counter(
-        "odys_engine_batch_queries_total",
-        help="query slots (incl. padding) across built batches",
-    ).inc(len(queries))
     q = len(queries)
     terms = np.full((q, t_max), NO_TERM, dtype=np.int32)
     n_terms = np.zeros(q, dtype=np.int32)

@@ -32,9 +32,11 @@ posting buffer with the *same* CSR + skip-table layout as the main
 
 **DeltaWriter** is the host-side transaction manager: ``insert_docs`` /
 ``delete_docs`` / ``update_docs`` mutate per-shard numpy mirrors and a
-monotone version counter; :meth:`DeltaWriter.device_delta` snapshots the
-mirrors into a :class:`ShardedDelta` pytree (fixed shapes — mutations
-never retrigger XLA compilation).  New documents take the next global
+monotone version counter; :meth:`DeltaWriter.host_delta` snapshots the
+mirrors into a :class:`ShardedDelta` pytree of numpy arrays (fixed shapes —
+mutations never retrigger XLA compilation) and
+:meth:`DeltaWriter.device_delta` places it.  A publish is those two steps:
+the serving path times each where it runs.  New documents take the next global
 docIDs and stripe across shards with the existing ``d % ns`` map, so
 :func:`repro.core.index.local_to_global_docids` needs no change.
 
@@ -233,8 +235,9 @@ class DeltaWriter:
         self.n_docs = corpus.n_docs            # total, including inserts
         self._delta_docs: set[int] = set()     # gids whose live postings are in delta
         self._version = 0
-        self._snapshot: ShardedDelta | None = None
+        self._snapshot: ShardedDelta | None = None   # host arrays
         self._snapshot_version = -1
+        self._placed: tuple[ShardedDelta, ShardedDelta] | None = None
 
     # ------------------------------------------------------------------
     # construction / rebase
@@ -244,7 +247,7 @@ class DeltaWriter:
         st = _ShardState(
             lengths=np.zeros(self.n_terms, dtype=np.int32),
             # 2-D host-side write mirrors, flattened + tile-padded only
-            # at snapshot time in device_delta().
+            # at snapshot time in host_delta().
             # lint: allow(posting-alloc)
             postings=np.full(
                 (self.n_terms, self.term_capacity), INVALID_DOC, dtype=np.int32
@@ -515,11 +518,21 @@ class DeltaWriter:
         return frozenset(self._delta_docs)
 
     def device_delta(self) -> ShardedDelta:
-        """Snapshot the host mirrors into a stacked device pytree.
+        """:meth:`host_delta` placed on the default device, cached per
+        snapshot."""
+        host = self.host_delta()
+        if self._placed is None or self._placed[0] is not host:
+            self._placed = (host, ShardedDelta(*(jnp.asarray(x) for x in host)))
+        return self._placed[1]
+
+    def host_delta(self) -> ShardedDelta:
+        """Snapshot the host mirrors into a stacked pytree of numpy arrays,
+        the host half of a publish (the caller places it).
 
         Shapes are fixed at construction, so repeated snapshots never
         retrigger compilation of jitted query functions; the snapshot is
-        cached per version (mutation batches invalidate it).
+        cached per version (mutation batches invalidate it) and its
+        arrays are never written again.
         """
         if self._snapshot is not None and self._snapshot_version == self._version:
             return self._snapshot
@@ -558,13 +571,13 @@ class DeltaWriter:
             (np.arange(self.n_terms, dtype=np.int32) * cap)[None], (ns, self.n_terms)
         )
         self._snapshot = ShardedDelta(
-            offsets=jnp.asarray(np.ascontiguousarray(offsets)),
-            lengths=jnp.asarray(lengths),
-            postings=jnp.asarray(postings),
-            attrs=jnp.asarray(attrs),
-            block_max=jnp.asarray(block_max),
-            doc_flags=jnp.asarray(np.stack([s.doc_flags for s in self._shards])),
-            doc_site=jnp.asarray(np.stack([s.doc_site for s in self._shards])),
+            offsets=np.ascontiguousarray(offsets),
+            lengths=lengths,
+            postings=postings,
+            attrs=attrs,
+            block_max=block_max,
+            doc_flags=np.stack([s.doc_flags for s in self._shards]),
+            doc_site=np.stack([s.doc_site for s in self._shards]),
         )
         self._snapshot_version = self._version
         export_index_bytes(int(postings.nbytes), None, kind="delta")
@@ -687,7 +700,7 @@ class ShardedDeltaWriter(DeltaWriter):
       cross-stream conflict race (e.g. update of a doc another master
       deleted, or a capacity-exhausted insert) is dropped and counted on
       ``odys_ingest_conflicts_total`` instead of poisoning the queue.
-    - :meth:`device_delta` publishes under :meth:`frozen` (all shard locks,
+    - :meth:`host_delta` publishes under :meth:`frozen` (all shard locks,
       re-entrant) and stamps the snapshot with the
       :class:`VectorVersion` ``(epoch, per-shard seqs)``; per-shard rows
       are cached by their ``(epoch, seq)`` so a publish recomputes the
@@ -779,7 +792,7 @@ class ShardedDeltaWriter(DeltaWriter):
     def frozen(self):
         """Exclusive section: allocation + every shard quiesced.
 
-        Publish (:meth:`device_delta`) and compaction
+        Publish (:meth:`host_delta`) and compaction
         (:func:`repro.indexing.compaction.compact`) run under this so they
         observe a cross-shard-consistent state.  Locks are re-entrant, so
         compaction's fold -> publish -> rebase nesting is fine.  Queued
@@ -929,7 +942,7 @@ class ShardedDeltaWriter(DeltaWriter):
             super().rebase(folded, **kw)
             self._shard_rows = [None] * self.ns
 
-    def device_delta(self) -> ShardedDelta:
+    def host_delta(self) -> ShardedDelta:
         """Publish: snapshot the shard mirrors, stamped with the
         :class:`VectorVersion`.  Shards whose ``(epoch, seq)`` did not move
         since the last publish reuse their cached flattened rows (the skip
@@ -989,15 +1002,13 @@ class ShardedDeltaWriter(DeltaWriter):
                 (ns, self.n_terms),
             )
             self._snapshot = ShardedDelta(
-                offsets=jnp.asarray(np.ascontiguousarray(offsets)),
-                lengths=jnp.asarray(
-                    np.stack([s.lengths for s in self._shards])
-                ),
-                postings=jnp.asarray(postings),
-                attrs=jnp.asarray(attrs),
-                block_max=jnp.asarray(block_max),
-                doc_flags=jnp.asarray(flags),
-                doc_site=jnp.asarray(sites),
+                offsets=np.ascontiguousarray(offsets),
+                lengths=np.stack([s.lengths for s in self._shards]),
+                postings=postings,
+                attrs=attrs,
+                block_max=block_max,
+                doc_flags=flags,
+                doc_site=sites,
             )
             self._snapshot_version = ver
             export_index_bytes(int(postings.nbytes), None, kind="delta")

@@ -8,7 +8,9 @@ slave top-k, master merge) as a live, exported signal:
   :class:`NullRegistry` is the process default, so instrumentation is
   zero-cost until :func:`enable` is called;
 - :mod:`repro.obs.trace`      — :class:`QuerySpan`, the per-query phase
-  record the scheduler populates, plus a folding aggregator;
+  record the scheduler populates, a folding aggregator, and
+  :class:`PhaseClock`, which times a batch's phases and writes them as
+  ``odys.<phase>`` profiler annotations;
 - :mod:`repro.obs.residual`   — the online Formula (18) monitor comparing
   measured response against the fitted hybrid model;
 - :mod:`repro.obs.exposition` — Prometheus text + JSON rendering, both
@@ -34,5 +36,6 @@ from repro.obs.trace import (  # noqa: F401
     PHASES,
     WALL_PHASES,
     PhaseAggregator,
+    PhaseClock,
     QuerySpan,
 )

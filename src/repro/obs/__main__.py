@@ -32,7 +32,6 @@ REQUIRED_FAMILIES = {
     "odys_batch_service_seconds": "histogram",
     "odys_queries_submitted_total": "counter",
     "odys_batches_dispatched_total": "counter",
-    "odys_engine_batches_built_total": "counter",
     "odys_model_residual": "gauge",
 }
 
@@ -87,8 +86,8 @@ def _cmd_demo(args) -> int:
 
     import numpy as np
 
-    # process-wide enable: the engine's batch-construction counters report
-    # through the process default, not a constructor-injected registry
+    # process-wide enable: components that report through the process
+    # default (the kernels' work-list gauges) report here too
     reg = enable()
     svc, cal = _build_pipeline(reg)
     agg = PhaseAggregator(registry=reg)

@@ -7,7 +7,8 @@ every admitted query as it moves through the serving pipeline
 latency histograms and the model-residual monitor
 (:mod:`repro.obs.residual`).
 
-Span phases (:data:`PHASES`), in pipeline order:
+Span phases (:data:`PHASES`), in pipeline order, children indented under
+their parent (a child's time is part of its parent's):
 
 - ``admission_wait``   — submit → the batch former pops the query's bucket
   (the queueing + formation-deadline component; scheduler clock domain, so
@@ -15,9 +16,31 @@ Span phases (:data:`PHASES`), in pipeline order:
 - ``formation_wait``   — batch formed → service start on the routed set
   (the set-availability wait; scheduler clock domain);
 - ``cache_lookup``     — result-cache probe at admission (wall domain);
-- ``route``            — multi-set router decision (wall domain);
+- ``schedule``         — ``MasterScheduler._dispatch``'s entry → the
+  executor call: batch formation, routing, the dispatch-time cache
+  recheck (wall domain);
+
+  - ``route``          — multi-set router decision (wall domain);
+
 - ``slave_dispatch``   — host-side batch construction + device dispatch of
-  the jitted query program (wall domain);
+  the jitted query program (wall domain):
+
+  - ``batch_build``    — ``make_query_batch``, the padded query arrays;
+  - ``delta_publish``  — only on a batch whose delta snapshot version
+    differs from the placed one:
+
+    - ``delta_rebuild`` — ``DeltaWriter.host_delta``, the numpy snapshot
+      of the writer's mirrors;
+    - ``delta_place``   — ``DeltaWriter.device_delta`` and ``device_put``
+      of that snapshot on the mesh.  No host sync is added to wait for
+      the transfer: the phase times the placement calls, which return
+      once the runtime has taken the host arrays (on a TPU v5e that is
+      after their host-to-device copy, whose events lie on the runtime's
+      threads inside the phase);
+
+  - ``launch``         — the call of the jitted
+    ``distributed_query_topk``/``replicated_query_topk`` until it returns;
+
 - ``master_merge``     — the batch-boundary sync: the wait for the device
   batch, which fuses slave top-k and the master merge in one jitted
   program.  Device work is timed **only** here, at the batch boundary —
@@ -27,36 +50,109 @@ Span phases (:data:`PHASES`), in pipeline order:
 Two clock domains, by design: the waits are measured on the scheduler's
 injectable clock (coherent under virtual-time replay), the service phases
 on a real monotonic wall clock (:data:`WALL_PHASES` labels which is
-which).  Batch-level phases (route, slave_dispatch, master_merge,
-finalize) are attributed to every query in the batch via batch membership
-— each co-batched span carries the full batch duration plus
-``batch_queries`` so aggregators can normalize per query when they want
-throughput rather than latency.
+which).  Batch-level phases (schedule and everything after it) are
+attributed to every query in the batch via batch membership — each
+co-batched span carries the full batch duration plus ``batch_queries`` so
+aggregators can normalize per query when they want throughput rather
+than latency.
+
+**The same phases on the profiler's clock.**  A :class:`PhaseClock` times
+one batch's wall-domain phases and, at exactly the same boundaries, opens
+a ``jax.profiler.TraceAnnotation`` named ``odys.<phase>`` that carries the
+batch id as the event stat ``batch``.  A profiler trace therefore nests
+the phases as the list above does, under an ``odys.step`` event that
+spans the whole of ``MasterScheduler._dispatch`` (its self time is the
+scheduler's bookkeeping after the executor returns).  Mutations are
+annotated as ``odys.mutation_apply`` with the writer ``version`` they
+produced.  Annotations record only while a profiler trace runs, and are
+opened only when the scheduler traces (``MasterScheduler.trace``, on iff
+the registry is live): with tracing off, each phase boundary costs one
+branch.
 """
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Callable
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.registry import MetricsRegistry, get_registry
 
-__all__ = ["PHASES", "WALL_PHASES", "PhaseAggregator", "QuerySpan"]
+__all__ = [
+    "ANNOTATION_PREFIX",
+    "PHASES",
+    "WALL_PHASES",
+    "PhaseAggregator",
+    "PhaseClock",
+    "QuerySpan",
+    "annotation",
+]
 
 PHASES = (
     "admission_wait",
     "formation_wait",
     "cache_lookup",
+    "schedule",
     "route",
     "slave_dispatch",
+    "batch_build",
+    "delta_publish",
+    "delta_rebuild",
+    "delta_place",
+    "launch",
     "master_merge",
     "finalize",
 )
 
 #: Phases measured on the real monotonic wall clock; the rest are in the
 #: scheduler's (possibly virtual) clock domain.
-WALL_PHASES = frozenset(
-    ("cache_lookup", "route", "slave_dispatch", "master_merge", "finalize")
-)
+WALL_PHASES = frozenset(PHASES) - {"admission_wait", "formation_wait"}
+
+#: Name prefix of the program's profiler annotations.
+ANNOTATION_PREFIX = "odys."
+
+
+def annotation(name: str, **stats) -> TraceAnnotation:
+    """The profiler annotation ``odys.<name>`` carrying ``stats`` as event
+    stats; it records an event only while a profiler trace runs."""
+    return TraceAnnotation(ANNOTATION_PREFIX + name, **stats)
+
+
+class PhaseClock:
+    """One batch's wall-domain phases, timed and annotated together.
+
+    :meth:`open` starts a phase's timer and its ``odys.<phase>`` profiler
+    annotation (stat ``batch`` = ``batch_id``); :meth:`close` ends both and
+    adds the duration to :attr:`phases`.  Phases nest: closing a phase
+    first closes any phase opened inside it that is still open, so an
+    early return or an exception inside a parent never leaves a child
+    annotation dangling once the parent closes.
+    """
+
+    __slots__ = ("batch_id", "phases", "_clock", "_open")
+
+    def __init__(self, batch_id: int, clock: Callable[[], float] = time.perf_counter):
+        self.batch_id = batch_id
+        self.phases: dict[str, float] = {}
+        self._clock = clock
+        self._open: list[tuple[str, TraceAnnotation, float]] = []
+
+    def open(self, phase: str) -> None:
+        ann = annotation(phase, batch=self.batch_id)
+        ann.__enter__()
+        self._open.append((phase, ann, self._clock()))
+
+    def close(self, phase: str) -> float:
+        """End ``phase`` (and whatever is still open inside it); returns
+        its duration in seconds."""
+        while True:
+            name, ann, t0 = self._open.pop()
+            dt = self._clock() - t0
+            ann.__exit__(None, None, None)
+            self.phases[name] = self.phases.get(name, 0.0) + dt
+            if name == phase:
+                return dt
 
 
 @dataclasses.dataclass

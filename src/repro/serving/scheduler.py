@@ -58,7 +58,7 @@ from typing import Any, Callable, Sequence
 
 from repro.core.perfmodel import sojourn
 from repro.obs.registry import MetricsRegistry, get_registry
-from repro.obs.trace import PHASES, QuerySpan
+from repro.obs.trace import PHASES, PhaseClock, QuerySpan
 
 __all__ = [
     "CacheStats",
@@ -382,14 +382,15 @@ class MasterScheduler:
         Metrics sink (:mod:`repro.obs.registry`).  Default: the process
         registry — a no-op unless ``repro.obs.enable()`` was called.
     trace:
-        Allocate a :class:`~repro.obs.trace.QuerySpan` per ticket.
-        Default (``None``): trace iff the registry is live.
-    exec_phases_fn:
-        Called once after each executor return; may yield a
-        ``{phase: seconds}`` dict splitting the batch's service into
-        wall-domain sub-phases (the search service reports
-        slave_dispatch / master_merge / finalize through this).  Without
-        it the whole measured batch wall time lands in ``slave_dispatch``.
+        Allocate a :class:`~repro.obs.trace.QuerySpan` per ticket, time
+        each batch's phases on a :class:`~repro.obs.trace.PhaseClock` and
+        write them as ``odys.*`` profiler annotations.  Default
+        (``None``): trace iff the registry is live.  While a traced batch
+        runs, :attr:`batch_clock` is its clock: an executor adds its own
+        phases there (the search service splits its service into
+        slave_dispatch and its children, master_merge and finalize).  An
+        executor that adds no ``slave_dispatch`` has the whole measured
+        batch wall time land there.
     span_sink:
         Called with each *finished* span (dispatch completion or cache
         hit) — wire a :class:`~repro.obs.trace.PhaseAggregator` or
@@ -415,7 +416,6 @@ class MasterScheduler:
         wall_clock: Callable[[], float] = time.perf_counter,
         registry: MetricsRegistry | None = None,
         trace: bool | None = None,
-        exec_phases_fn: Callable[[], "dict[str, float] | None"] | None = None,
         span_sink: Callable[[QuerySpan], None] | None = None,
     ):
         assert batch_size >= 1
@@ -425,7 +425,7 @@ class MasterScheduler:
         self.registry = reg
         self.trace = bool(reg.enabled) if trace is None else bool(trace)
         self.span_sink = span_sink
-        self._exec_phases_fn = exec_phases_fn
+        self.batch_clock: PhaseClock | None = None   # the traced batch in flight
         self.executor = executor
         self.batch_size = batch_size
         self.t_max_buckets = buckets
@@ -635,7 +635,26 @@ class MasterScheduler:
         return best
 
     def _dispatch(self, key: tuple[int, int]) -> list[QueryTicket]:
-        """Form and execute one micro-batch from bucket ``key``."""
+        """Form and execute one micro-batch from bucket ``key``.
+
+        Traced, the whole call is the batch's ``odys.step`` annotation, and
+        its ``schedule`` phase runs from here to the executor call."""
+        if not self.trace:
+            return self._form_and_run(key, None)
+        # the id the batch gets if it runs (a short-circuited batch counts)
+        clock = PhaseClock(self.n_batches, self._wall_clock)
+        clock.open("step")
+        clock.open("schedule")
+        self.batch_clock = clock
+        try:
+            return self._form_and_run(key, clock)
+        finally:
+            self.batch_clock = None
+            clock.close("step")
+
+    def _form_and_run(
+        self, key: tuple[int, int], clock: PhaseClock | None
+    ) -> list[QueryTicket]:
         t_max, k = key
         queue = self._queues[key]
         t_form = self._now()        # batch formation instant (scheduler clock)
@@ -648,7 +667,8 @@ class MasterScheduler:
         if not batch:
             return []
         real = [t for t in batch if t.qid >= 0]
-        route_w0 = self._wall_clock() if self.trace else 0.0
+        if clock is not None:
+            clock.open("route")
         try:
             sref = self.router.route(len(real))
         except BaseException:
@@ -656,7 +676,7 @@ class MasterScheduler:
             # router): the popped tickets must survive for a later retry
             self._queues.setdefault(key, [])[:0] = real
             raise
-        route_wall = self._wall_clock() - route_w0 if self.trace else 0.0
+        route_wall = clock.close("route") if clock is not None else 0.0
         version = self._version_fn()
         queries = [(list(t.terms), t.site) for t in batch]
         start = max(self._now(), sref.busy_until)
@@ -719,6 +739,8 @@ class MasterScheduler:
             self._m_pad_fraction.set(1.0)
             self._m_queue_depth.set(self.pending())
             return real
+        if clock is not None:
+            clock.close("schedule")
         # Measured service stays on the real monotonic wall clock — never
         # the (possibly virtual) scheduler clock; the span labels it so.
         wall0 = self._wall_clock()
@@ -731,10 +753,12 @@ class MasterScheduler:
             self._queues.setdefault(key, [])[:0] = real
             raise
         wall = self._wall_clock() - wall0
-        exec_phases = (
-            self._exec_phases_fn() if self._exec_phases_fn is not None
-            else None
-        )
+        # schedule, route and the executor's own phases (wall domain)
+        phases = dict(clock.phases) if clock is not None else {}
+        if "slave_dispatch" not in phases:
+            # opaque executor: the whole measured batch service is one
+            # undecomposed dispatch phase
+            phases["slave_dispatch"] = wall
         if key in self._warm_keys:
             self._service_ewma = (
                 wall if self._service_ewma is None
@@ -775,14 +799,8 @@ class MasterScheduler:
                 span.pad_fraction = pad_fraction
                 span.add("admission_wait", t_form - span.submit_time)
                 span.add("formation_wait", start - t_form)
-                span.add("route", route_wall)
-                if exec_phases:
-                    for phase, dt in exec_phases.items():
-                        span.add(phase, dt)
-                else:
-                    # opaque executor: the whole measured batch service is
-                    # one undecomposed dispatch phase
-                    span.add("slave_dispatch", wall)
+                for phase, dt in phases.items():
+                    span.add(phase, dt)
                 span.finish_time = finish
                 for phase, dt in span.phases.items():
                     hist = self._m_phase.get(phase)

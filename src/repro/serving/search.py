@@ -53,6 +53,7 @@ from repro.data.corpus import Corpus
 from repro.indexing.compaction import compact as _compact
 from repro.indexing.delta import DeltaWriter
 from repro.obs.registry import MetricsRegistry, get_registry
+from repro.obs.trace import PhaseClock, annotation
 from repro.serving.scheduler import MasterScheduler, QueryTicket
 
 
@@ -198,7 +199,10 @@ class SearchService:
 
             router = HealthAwareRouter(n_sets, set_health)
         self.registry = registry if registry is not None else get_registry()
-        self._exec_phases: dict[str, float] | None = None
+        self._m_mutation = self.registry.histogram(
+            "odys_mutation_apply_seconds",
+            help="one insert/delete/update call on the writer, compaction "
+                 "included (wall domain; timed while tracing)")
         self.scheduler = MasterScheduler(
             self._execute,
             batch_size=batch_size,
@@ -213,7 +217,6 @@ class SearchService:
             version_fn=self._snapshot_version,
             width_fn=self._query_width,
             registry=self.registry,
-            exec_phases_fn=self._take_exec_phases,
             span_sink=span_sink,
         )
 
@@ -228,18 +231,31 @@ class SearchService:
 
     def insert(self, docs) -> list[int]:
         """Insert ``(terms, site)`` documents; returns global docIDs."""
-        gids = self._require_writer().insert_docs(docs)
-        self._maybe_compact()
-        return gids
+        return self._mutate(lambda w: w.insert_docs(docs))
 
     def delete(self, docids) -> None:
-        self._require_writer().delete_docs(docids)
-        self._maybe_compact()
+        self._mutate(lambda w: w.delete_docs(docids))
 
     def update(self, updates) -> None:
         """Apply ``(docid, new_terms, new_site_or_None)`` updates."""
-        self._require_writer().update_docs(updates)
-        self._maybe_compact()
+        self._mutate(lambda w: w.update_docs(updates))
+
+    def _mutate(self, apply):
+        """``apply`` the writer, then compact if due.  Traced, the call is
+        an ``odys.mutation_apply`` annotation carrying the writer version
+        it produced, and its time feeds ``odys_mutation_apply_seconds``."""
+        writer = self._require_writer()
+        if not self.scheduler.trace:
+            out = apply(writer)
+            self._maybe_compact()
+            return out
+        with annotation("mutation_apply") as ann:
+            t0 = time.perf_counter()
+            out = apply(writer)
+            self._maybe_compact()
+            self._m_mutation.observe(time.perf_counter() - t0)
+            ann.set_metadata(version=self.writer.version)
+        return out
 
     def compact(
         self,
@@ -308,52 +324,79 @@ class SearchService:
         ]
         self._set_delta.clear()
 
-    def _delta_snapshot(self, set_id: int | None = None):
+    def _delta_snapshot(
+        self, set_id: int | None = None, clock: PhaseClock | None = None
+    ):
         """Current delta snapshot placed on ``set_id``'s slice (None: the
         service mesh), cached per (placement, writer version) — a new
-        publish on any shard re-places."""
+        publish on any shard re-places.  A publish is the batch's
+        ``delta_publish`` phase: the writer's host snapshot
+        (``delta_rebuild``), then its placement through
+        ``writer.device_delta`` and onto the mesh (``delta_place``: no sync
+        is added; the placement calls return once the runtime has taken
+        the host arrays)."""
         if self.writer is None:
             return None
-        snap = self.writer.device_delta()
+        # read before the snapshot: a mutation racing the publish can only
+        # make the cache key older than the content, never newer
         ver = self.writer.version
         cached = self._set_delta.get(set_id)
         if cached is not None and cached[0] == ver:
             return cached[1]
+        if clock is not None:
+            clock.open("delta_publish")
+            clock.open("delta_rebuild")
+        self.writer.host_delta()        # cached for device_delta below
+        if clock is not None:
+            clock.close("delta_rebuild")
+            clock.open("delta_place")
+        snap = self.writer.device_delta()
         mesh = self.mesh if set_id is None else self.set_meshes[set_id]
         placed = jax.device_put(snap, NamedSharding(mesh, P("data")))
+        if clock is not None:
+            clock.close("delta_publish")
         self._set_delta[set_id] = (ver, placed)
         return placed
 
     def _run_engine(
-        self, queries, *, t_max: int, k: int, set_id: int | None = None
+        self,
+        queries,
+        *,
+        t_max: int,
+        k: int,
+        set_id: int | None = None,
+        clock: PhaseClock | None = None,
     ) -> SearchResult:
         """One batch end-to-end on the mesh at the given padded shapes.
 
         With ``set_meshes`` configured and a ``set_id``, the batch runs on
         that set's disjoint slice via :func:`replicated_query_topk`;
-        otherwise on the shared service mesh."""
+        otherwise on the shared service mesh.  ``clock`` (tracing) times
+        the batch build, the delta publish and the launch."""
+        if clock is not None:
+            clock.open("batch_build")
         batch = make_query_batch(
             queries, t_max=t_max, meta=self.meta, strategy=self.strategy
         )
+        if clock is not None:
+            clock.close("batch_build")
         if set_id is not None and self.set_meshes is not None:
-            return replicated_query_topk(
-                self._set_index[set_id],
-                batch,
-                self._delta_snapshot(set_id),
-                mesh=self.set_meshes[set_id],
-                ns=self.ns,
-                k=k,
-                window=self.window,
-                attr_strategy=self.strategy,
-                merge=self.merge,
-                backend=self.backend,
-                interpret=self.interpret,
+            run, index, mesh = (
+                replicated_query_topk, self._set_index[set_id],
+                self.set_meshes[set_id],
             )
-        return distributed_query_topk(
-            self.index,
+        else:
+            run, index, mesh, set_id = (
+                distributed_query_topk, self.index, self.mesh, None
+            )
+        delta = self._delta_snapshot(set_id, clock)
+        if clock is not None:
+            clock.open("launch")
+        res = run(
+            index,
             batch,
-            self._delta_snapshot(),
-            mesh=self.mesh,
+            delta,
+            mesh=mesh,
             ns=self.ns,
             k=k,
             window=self.window,
@@ -362,15 +405,9 @@ class SearchService:
             backend=self.backend,
             interpret=self.interpret,
         )
-
-    def _take_exec_phases(self) -> dict[str, float] | None:
-        """Return-and-clear the last :meth:`_execute`'s phase breakdown.
-
-        The scheduler calls this right after each executor return (its
-        ``exec_phases_fn`` hook) to fold the wall-domain service phases
-        into the batch's spans."""
-        phases, self._exec_phases = self._exec_phases, None
-        return phases
+        if clock is not None:
+            clock.close("launch")
+        return res
 
     def _execute(self, queries, t_max: int, k: int, set_id: int) -> list[SearchHit]:
         """Scheduler executor: run one formed micro-batch.
@@ -381,19 +418,28 @@ class SearchService:
         otherwise the in-process deployment time-shares one mesh across
         sets.
 
-        When the registry is live, the batch's service is decomposed at
-        the batch boundary only — dispatch of the jitted program, the
-        ``np.asarray`` device sync that was already on this path (the
-        fused slave top-k + master merge completes under it), and the
-        host-side result extraction.  No host syncs are added inside the
-        device program."""
-        timed = self.registry.enabled
-        w0 = time.perf_counter() if timed else 0.0
-        res = self._run_engine(queries, t_max=t_max, k=k, set_id=set_id)
-        w1 = time.perf_counter() if timed else 0.0
+        When the scheduler traces, the batch's service is decomposed on
+        its :attr:`~MasterScheduler.batch_clock`, at the batch boundary
+        only — host build and dispatch of the jitted program
+        (``slave_dispatch`` and its children), the ``np.asarray`` device
+        sync that was already on this path (``master_merge``: the fused
+        slave top-k + master merge completes under it), and the host-side
+        result extraction (``finalize``).  No host syncs are added inside
+        the device program."""
+        clock = self.scheduler.batch_clock
+        if clock is not None:
+            clock.open("slave_dispatch")
+        res = self._run_engine(
+            queries, t_max=t_max, k=k, set_id=set_id, clock=clock
+        )
+        if clock is not None:
+            clock.close("slave_dispatch")
+            clock.open("master_merge")
         docs = np.asarray(res.docids)
         hits = np.asarray(res.n_hits)
-        w2 = time.perf_counter() if timed else 0.0
+        if clock is not None:
+            clock.close("master_merge")
+            clock.open("finalize")
         out = [
             SearchHit(
                 docids=[int(d) for d in row if d != INVALID_DOC],
@@ -401,13 +447,8 @@ class SearchService:
             )
             for row, h in zip(docs, hits)
         ]
-        if timed:
-            w3 = time.perf_counter()
-            self._exec_phases = {
-                "slave_dispatch": w1 - w0,   # host build + async dispatch
-                "master_merge": w2 - w1,     # batch-boundary device sync
-                "finalize": w3 - w2,         # host result extraction
-            }
+        if clock is not None:
+            clock.close("finalize")
         return out
 
     def submit(

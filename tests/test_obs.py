@@ -31,7 +31,13 @@ from repro.obs.registry import (
     set_registry,
 )
 from repro.obs.residual import ModelResidualMonitor
-from repro.obs.trace import PHASES, WALL_PHASES, PhaseAggregator, QuerySpan
+from repro.obs.trace import (
+    PHASES,
+    WALL_PHASES,
+    PhaseAggregator,
+    PhaseClock,
+    QuerySpan,
+)
 from repro.serving.router import HealthAwareRouter
 from repro.serving.scheduler import MasterScheduler
 from repro.serving.search import SearchService
@@ -303,7 +309,6 @@ def test_spans_through_real_engine(setup, backend):
     assert len(sink) == 3               # every finished span reached the sink
     txt = to_prometheus(reg)
     assert "odys_phase_seconds_bucket" in txt
-    assert "odys_engine_batches_built_total" not in txt  # process-default only
 
 
 def test_spans_under_virtual_time_replay(setup):
@@ -329,6 +334,129 @@ def test_spans_under_virtual_time_replay(setup):
         waits = (span.phases.get("admission_wait", 0.0)
                  + span.phases.get("formation_wait", 0.0))
         assert waits <= span.response_time + 1e-9
+
+
+# ------------------------------------- batch phases, profiler annotations
+
+SERVICE_PHASES = ("batch_build", "launch")
+PUBLISH_PHASES = ("delta_publish", "delta_rebuild", "delta_place")
+
+
+def make_updatable(setup, registry):
+    return make_service(setup, cache_size=0, batch_size=2, registry=registry,
+                        updatable=True, corpus=setup[0], term_capacity=128,
+                        doc_headroom=64)
+
+
+def _batch_span(svc, queries):
+    tickets = [svc.submit(terms, site) for terms, site in queries]
+    svc.drain()
+    assert all(t.done for t in tickets)
+    return tickets[0].span
+
+
+def test_phase_clock_nests_and_closes_children():
+    ticks = iter(range(100))
+    clock = PhaseClock(4, clock=lambda: float(next(ticks)))
+    clock.open("step")          # t=0
+    clock.open("schedule")      # t=1
+    clock.open("route")         # t=2
+    assert clock.close("route") == 1.0                  # t=3
+    clock.close("step")         # closes schedule (t=4), then step (t=5)
+    assert clock.phases == {"route": 1.0, "schedule": 3.0, "step": 5.0}
+    assert clock.batch_id == 4
+
+
+def test_publish_phases_only_on_the_batch_after_a_mutation(setup):
+    svc = make_updatable(setup, MetricsRegistry())
+    first = _batch_span(svc, [([3], None), ([4], None)])
+    assert set(PUBLISH_PHASES) <= set(first.phases)    # version 0 is placed
+    quiet = _batch_span(svc, [([3], None), ([5], None)])
+    assert not set(PUBLISH_PHASES) & set(quiet.phases)
+    svc.insert([(np.array([3, 9], np.int32), 1)])
+    after = _batch_span(svc, [([3, 9], None), ([9], None)])
+    assert set(PUBLISH_PHASES) <= set(after.phases)
+    again = _batch_span(svc, [([3, 9], None), ([4], None)])
+    assert not set(PUBLISH_PHASES) & set(again.phases)
+    assert svc.registry.histogram("odys_mutation_apply_seconds").count == 1
+
+
+def test_child_phases_fit_inside_their_parents(setup):
+    svc = make_updatable(setup, MetricsRegistry())
+    _batch_span(svc, [([3], None), ([4], None)])
+    svc.delete([7])
+    span = _batch_span(svc, [([3], 2), ([9], None)])
+    ph = span.phases
+    for p in ("schedule", "route", "slave_dispatch", *SERVICE_PHASES,
+              *PUBLISH_PHASES, "master_merge", "finalize"):
+        assert p in ph and ph[p] >= 0.0, p
+    tol = 1e-4
+    assert ph["batch_build"] + ph["delta_publish"] + ph["launch"] <= (
+        ph["slave_dispatch"] + tol)
+    assert ph["delta_rebuild"] + ph["delta_place"] <= ph["delta_publish"] + tol
+    assert ph["route"] <= ph["schedule"] + tol
+    assert set(ph) <= set(PHASES)
+
+
+def _odys_events(log_dir):
+    from jax.profiler import ProfileData
+
+    (path,) = list(Path(log_dir).rglob("*.xplane.pb"))
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for plane in ProfileData.from_file(str(path)).planes
+            for line in plane.lines for e in line.events
+            if e.name.startswith("odys.")]
+
+
+def test_phases_are_written_on_the_profiler_clock(setup, tmp_path):
+    svc = make_updatable(setup, MetricsRegistry())
+    _batch_span(svc, [([3], None), ([4], None)])       # compile, place v0
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        svc.insert([(np.array([3, 9], np.int32), 2)])
+        spans = [_batch_span(svc, [([3, 9], None), ([9], 2)]),
+                 _batch_span(svc, [([3], None), ([4], None)])]
+    finally:
+        jax.profiler.stop_trace()
+    events = _odys_events(tmp_path)
+    names = {n for n, *_ in events}
+    assert {"odys.step", "odys.schedule", "odys.route", "odys.slave_dispatch",
+            "odys.batch_build", "odys.delta_publish", "odys.delta_rebuild",
+            "odys.delta_place", "odys.launch", "odys.master_merge",
+            "odys.finalize", "odys.mutation_apply"} <= names
+    (mut,) = [e for e in events if e[0] == "odys.mutation_apply"]
+    assert mut[3]["version"] == svc.writer.version
+    steps = {e[3]["batch"]: e for e in events if e[0] == "odys.step"}
+    assert sorted(steps) == [s.batch_id for s in spans]
+    for name, t0, t1, stats in events:
+        if name == "odys.mutation_apply":
+            continue
+        step = steps[stats["batch"]]                    # every one carries it
+        assert step[1] <= t0 <= t1 <= step[2], name
+    publishing = {e[3]["batch"] for e in events if e[0] == "odys.delta_publish"}
+    assert publishing == {spans[0].batch_id}
+
+
+def test_no_annotation_or_span_with_the_registry_off(setup, tmp_path,
+                                                      monkeypatch):
+    import repro.serving.scheduler as scheduler_mod
+
+    def refuse(*a, **k):
+        raise AssertionError("a PhaseClock was allocated with tracing off")
+
+    svc = make_updatable(setup, None)
+    assert not svc.scheduler.trace
+    monkeypatch.setattr(scheduler_mod, "PhaseClock", refuse)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        svc.insert([(np.array([3, 9], np.int32), 2)])
+        tickets = [svc.submit([3, 9]), svc.submit([9], 2)]
+        svc.drain()
+    finally:
+        jax.profiler.stop_trace()
+    assert all(t.done and t.span is None for t in tickets)
+    assert svc.scheduler.batch_clock is None
+    assert _odys_events(tmp_path) == []
 
 
 # ------------------------------------------------------------ aggregation
@@ -435,21 +563,6 @@ def test_disabled_registry_identical_results(setup):
     assert off == on
     assert not svc_off.scheduler.trace
     assert svc_on.scheduler.trace
-
-
-def test_engine_batch_counters_on_process_registry(setup):
-    corpus, sharded, meta, mesh = setup
-    from repro.core.engine import make_query_batch
-
-    prev = set_registry(MetricsRegistry())
-    try:
-        reg = get_registry()
-        make_query_batch([([3], None), ([4], 1)], t_max=2, meta=meta)
-        make_query_batch([([5], None)], t_max=2, meta=meta)
-        assert reg.counter("odys_engine_batches_built_total").value == 2
-        assert reg.counter("odys_engine_batch_queries_total").value == 3
-    finally:
-        set_registry(prev)
 
 
 # ------------------------------------------------------------- bench gate
