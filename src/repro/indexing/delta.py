@@ -32,13 +32,29 @@ posting buffer with the *same* CSR + skip-table layout as the main
 
 **DeltaWriter** is the host-side transaction manager: ``insert_docs`` /
 ``delete_docs`` / ``update_docs`` mutate per-shard numpy mirrors and a
-monotone version counter; :meth:`DeltaWriter.host_delta` snapshots the
-mirrors into a :class:`ShardedDelta` pytree of numpy arrays (fixed shapes —
-mutations never retrigger XLA compilation) and
-:meth:`DeltaWriter.device_delta` places it.  A publish is those two steps:
-the serving path times each where it runs.  New documents take the next global
-docIDs and stripe across shards with the existing ``d % ns`` map, so
+monotone version counter.  New documents take the next global docIDs and
+stripe across shards with the existing ``d % ns`` map, so
 :func:`repro.core.index.local_to_global_docids` needs no change.
+
+**Publish.**  :meth:`DeltaWriter.device_delta` returns the current version
+as a :class:`ShardedDelta` on the device (fixed shapes — mutations never
+retrigger compilation of the query programs).  A version usually differs
+from the placed one by a few term slabs and documents, so the writer
+stamps every ``(shard, term)`` slab with its op counter when a posting
+lands in or leaves it, and logs every document whose flags or site it
+writes.  A publish is two steps, which the serving path times apart:
+:meth:`DeltaWriter.host_publish` gathers, on the host, the whole rows of
+the slabs stamped after the placed snapshot, their lengths and skip-table
+rows, and the logged documents' entries, into one buffer padded to a
+fixed bucket (:data:`PATCH_BUCKETS`); ``device_delta`` copies it to the
+device, where one jitted scatter writes it into a *copy* of the placed
+snapshot.  Snapshots are never donated or written in place, so a batch in
+flight keeps reading the version it was dispatched with.  The first
+publish, the first after a :meth:`~DeltaWriter.rebase` (compaction, or a
+new generation's shapes), and one with more dirty slabs or documents than
+the last bucket place :meth:`DeltaWriter.host_delta` — the whole snapshot
+rebuilt from the mirrors, also the oracle the patch is tested against —
+and compile every bucket's scatter there, so no publish after it compiles.
 
 **Freshness semantics** (merge-on-read, see :mod:`repro.core.engine`):
 a query that starts after ``device_delta()`` returns sees every mutation
@@ -59,6 +75,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import itertools
 import threading
 from collections import deque
@@ -66,7 +83,9 @@ from typing import NamedTuple, Sequence
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core.index import (
     BLOCK,
@@ -153,6 +172,84 @@ def _pad_block(n: int) -> int:
     return _ceil_div(n, BLOCK) * BLOCK
 
 
+def _block_max_rows(rows: np.ndarray, lengths: np.ndarray) -> np.ndarray:
+    """Skip-table rows of whole term slabs ``rows`` (n, cap): per BLOCK the
+    max over the slab's *valid* postings (its first ``lengths`` entries),
+    INVALID_DOC for a block that holds none."""
+    n, cap = rows.shape
+    valid = np.arange(cap)[None] < lengths[:, None]
+    bm = np.where(valid, rows, -1).reshape(n, cap // BLOCK, BLOCK).max(axis=2)
+    return np.where(bm >= 0, bm, INVALID_DOC).astype(np.int32)
+
+
+#: Dirty-slab counts a patch publish pads to (its documents share the
+#: bucket), so every patch has one of a few fixed shapes.  A version with
+#: more dirty slabs or documents than the last bucket publishes in full.
+PATCH_BUCKETS = (64, 256, 1024, 4096)
+
+
+def _patch_parts(buf, rows: int, cap: int):
+    """Split a patch buffer of ``rows`` entries into its fields, each
+    ``(rows, width)``: slab shard, slab term, slab length, postings row,
+    attrs row, skip-table row, doc shard, doc local id, doc flags, doc
+    site.  Views on the host, static slices under jit."""
+    widths = (1, 1, 1, cap, cap, cap // BLOCK, 1, 1, 1, 1)
+    out, at = [], 0
+    for w in widths:
+        out.append(buf[at : at + rows * w].reshape(rows, w))
+        at += rows * w
+    return out
+
+
+def _patch_size(rows: int, cap: int) -> int:
+    return rows * (7 + 2 * cap + cap // BLOCK)
+
+
+def _put_rows(x, shard, start, rows):
+    """``x[shard[i], start[i] : start[i] + w] = rows[i]`` for every i; a
+    row whose shard is out of range (bucket padding) is dropped."""
+    dn = lax.ScatterDimensionNumbers(
+        update_window_dims=(1,), inserted_window_dims=(0,),
+        scatter_dims_to_operand_dims=(0, 1),
+    )
+    return lax.scatter(
+        x, jnp.concatenate([shard, start], axis=1), rows, dn,
+        unique_indices=True, mode=lax.GatherScatterMode.FILL_OR_DROP,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("rows",))
+def _apply_patch(lengths, postings, attrs, block_max, doc_flags, doc_site,
+                 buf, *, rows: int):
+    """The device half of a patch publish: a new snapshot's fields (all
+    but ``offsets``), the placed ones with the patch's slabs and documents
+    written over them.  Nothing is donated: the placed snapshot stays
+    valid for the batches still reading it."""
+    bpt = block_max.shape[1] // lengths.shape[1]
+    cap = bpt * BLOCK
+    s, t, ln, p, a, b, ds, dl, df, dsite = _patch_parts(buf, rows, cap)
+    return (
+        _put_rows(lengths, s, t, ln),
+        _put_rows(postings, s, t * cap, p),
+        _put_rows(attrs, s, t * cap, a),
+        _put_rows(block_max, s, t * bpt, b),
+        _put_rows(doc_flags, ds, dl, df),
+        _put_rows(doc_site, ds, dl, dsite),
+    )
+
+
+class _HostPublish(NamedTuple):
+    """The host half of one publish (:meth:`DeltaWriter.host_publish`)."""
+
+    version: int                # writer._version, read before the gather
+    base: int                   # version of the snapshot it patches; -1: full
+    full: ShardedDelta | None   # host_delta(), on a full publish
+    patch: np.ndarray | None    # int32 patch buffer (_patch_parts layout)
+    rows: int                   # its bucket
+    slabs: int                  # term slabs it ships
+    logs: tuple[int, ...]       # per shard, doc-log entries it covers
+
+
 @dataclasses.dataclass
 class _ShardState:
     """Host-side numpy mirror of one shard's delta."""
@@ -162,6 +259,11 @@ class _ShardState:
     attrs: np.ndarray      # int32[n_terms, cap]
     doc_flags: np.ndarray  # int32[nd_cap]
     doc_site: np.ndarray   # int32[nd_cap]
+    # Dirty tracking for the patch publish: the writer version each slab
+    # was last written at, and the local ids of documents written since
+    # the placed snapshot (duplicates allowed).
+    term_stamp: np.ndarray          # int64[n_terms]
+    doc_log: list = dataclasses.field(default_factory=list)
 
 
 class DeltaWriter:
@@ -237,7 +339,28 @@ class DeltaWriter:
         self._version = 0
         self._snapshot: ShardedDelta | None = None   # host arrays
         self._snapshot_version = -1
-        self._placed: tuple[ShardedDelta, ShardedDelta] | None = None
+        # The placed device snapshot, the version it holds, and the host
+        # half of the next publish.
+        self._dev: ShardedDelta | None = None
+        self._dev_version = -1
+        self._publish: _HostPublish | None = None
+        self._publish_metrics(get_registry())
+
+    def _publish_metrics(self, reg: MetricsRegistry) -> None:
+        self._m_publish_total = {
+            mode: reg.counter(
+                "odys_delta_publish_total",
+                help="delta versions placed on the device, by how: a patch "
+                     "of the dirty slabs or the whole snapshot",
+                mode=mode,
+            )
+            for mode in ("patch", "full")
+        }
+        self._m_publish_slabs = reg.histogram(
+            "odys_delta_publish_slabs",
+            help="term slabs shipped per delta publish",
+            buckets=tuple(float(4**i) for i in range(13)),
+        )
 
     # ------------------------------------------------------------------
     # construction / rebase
@@ -258,6 +381,7 @@ class DeltaWriter:
             ),
             doc_flags=np.zeros(self.nd_cap, dtype=np.int32),
             doc_site=np.full(self.nd_cap, INVALID_ATTR, dtype=np.int32),
+            term_stamp=np.zeros(self.n_terms, dtype=np.int64),
         )
         base_sites = base.doc_site[s::self.ns]
         st.doc_site[: base_sites.shape[0]] = base_sites
@@ -302,6 +426,7 @@ class DeltaWriter:
         self._base_n_docs = folded.n_docs
         self._shards = [self._fresh_shard(folded, s) for s in range(self.ns)]
         self._delta_docs = set()
+        self._dev = None      # every slab was reset: the next publish is full
         self._bump()
 
     # ------------------------------------------------------------------
@@ -317,6 +442,7 @@ class DeltaWriter:
         row[pos] = local
         arow[pos] = attr
         st.lengths[t] = ln + 1
+        self._stamp(st, t)
 
     def _remove_posting(self, st: _ShardState, t: int, local: int):
         ln = int(st.lengths[t])
@@ -329,6 +455,12 @@ class DeltaWriter:
         row[ln - 1] = INVALID_DOC
         arow[ln - 1] = INVALID_ATTR
         st.lengths[t] = ln - 1
+        self._stamp(st, t)
+
+    def _stamp(self, st: _ShardState, t: int):
+        # After the write, above any version a publish could have read
+        # before it: the slab ships with the next publish at the latest.
+        st.term_stamp[t] = self._version + 1
 
     def _posting_terms(self, gid: int) -> list[int]:
         """All term ids carrying postings for gid's *current* version."""
@@ -399,6 +531,7 @@ class DeltaWriter:
         for t in plist:
             self._insert_posting(st, t, local, site)
         st.doc_site[local] = site
+        st.doc_log.append(local)
         self._docs.append(terms_u)
         self._sites.append(int(site))
         self._delta_docs.add(gid)
@@ -424,6 +557,7 @@ class DeltaWriter:
                 self._remove_posting(st, t, local)
             self._delta_docs.discard(gid)
         st.doc_flags[local] |= DOC_DEAD
+        st.doc_log.append(local)
         self._docs[gid] = np.zeros(0, dtype=np.int32)
         self._bump(gid % self.ns)
 
@@ -477,6 +611,7 @@ class DeltaWriter:
         for t in new_plist:
             self._insert_posting(st, t, local, new_site)
         st.doc_site[local] = new_site
+        st.doc_log.append(local)
         self._docs[gid] = terms_u
         self._sites[gid] = new_site
         self._delta_docs.add(gid)
@@ -517,71 +652,159 @@ class DeltaWriter:
         """Global docIDs whose live postings are in the delta."""
         return frozenset(self._delta_docs)
 
+    def _exclusive(self):
+        """The section a publish runs in (the multi-writer freezes)."""
+        return contextlib.nullcontext()
+
     def device_delta(self) -> ShardedDelta:
-        """:meth:`host_delta` placed on the default device, cached per
-        snapshot."""
-        host = self.host_delta()
-        if self._placed is None or self._placed[0] is not host:
-            self._placed = (host, ShardedDelta(*(jnp.asarray(x) for x in host)))
-        return self._placed[1]
+        """The current version on the device — the one call through which
+        a published snapshot is obtained; cached per version.
+
+        Places :meth:`host_publish`'s patch (a jitted scatter into a copy
+        of the placed snapshot) or full snapshot.  A returned snapshot is
+        never written again."""
+        with self._exclusive():
+            pub = self.host_publish()
+            if pub is None:
+                return self._dev
+            if pub.full is not None:
+                dev = jax.device_put(pub.full)
+                self._warm_patches(dev)
+            else:
+                dev = ShardedDelta(self._dev.offsets, *_apply_patch(
+                    *self._dev[1:], jax.device_put(pub.patch), rows=pub.rows
+                ))
+            for st, n in zip(self._shards, pub.logs):
+                del st.doc_log[:n]
+            self._dev, self._dev_version, self._publish = dev, pub.version, None
+            self._m_publish_total["full" if pub.full is not None else "patch"].inc()
+            self._m_publish_slabs.observe(pub.slabs)
+            return dev
+
+    def _warm_patches(self, dev: ShardedDelta) -> None:
+        """Compile every bucket's patch for ``dev``'s shapes (a cache hit
+        after the first full publish of a generation): an all-padding
+        patch writes nothing, and its result is dropped."""
+        for rows in PATCH_BUCKETS:
+            buf = np.zeros(_patch_size(rows, self.term_capacity), np.int32)
+            parts = _patch_parts(buf, rows, self.term_capacity)
+            parts[0][:, 0] = parts[6][:, 0] = self.ns + np.arange(rows)
+            _apply_patch(*dev[1:], jax.device_put(buf), rows=rows)
+
+    def host_publish(self) -> _HostPublish | None:
+        """The host half of a publish, cached per version (None: the placed
+        snapshot is current).
+
+        Gathers the slabs stamped after the placed snapshot — whole rows of
+        the current state with their lengths and skip-table rows — and the
+        logged documents' flags and sites into one buffer, padded to the
+        smallest bucket that holds both counts (padding rows name shards
+        past the last, so the scatter drops them).  The version is read
+        before the gather: a write racing it is stamped later and ships
+        again with the next publish, which its whole-row content makes
+        harmless.  With no placed snapshot, after a rebase, or past the
+        last bucket, the publish is :meth:`host_delta` in full.
+        """
+        with self._exclusive():
+            ver = self._version
+            base = self._dev_version if self._dev is not None else -1
+            if base == ver:
+                return None
+            pub = self._publish
+            if pub is not None and (pub.version, pub.base) == (ver, base):
+                return pub
+            logs = tuple(len(st.doc_log) for st in self._shards)
+            rows = None
+            if base >= 0:
+                slabs = [np.flatnonzero(st.term_stamp > base) for st in self._shards]
+                docs = [
+                    np.unique(np.asarray(st.doc_log[:n], np.int32))
+                    for st, n in zip(self._shards, logs)
+                ]
+                n_slabs = sum(len(ts) for ts in slabs)
+                n_docs = sum(len(ds) for ds in docs)
+                rows = next(
+                    (b for b in PATCH_BUCKETS if b >= max(n_slabs, n_docs)), None
+                )
+            if rows is None:
+                self._publish = _HostPublish(
+                    ver, base, self.host_delta(), None, 0,
+                    self.ns * self.n_terms, logs,
+                )
+                return self._publish
+            cap = self.term_capacity
+            buf = np.zeros(_patch_size(rows, cap), np.int32)
+            s_, t_, ln_, p_, a_, b_, ds_, dl_, df_, dsite_ = _patch_parts(
+                buf, rows, cap
+            )
+            s_[n_slabs:, 0] = self.ns + np.arange(rows - n_slabs)
+            ds_[n_docs:, 0] = self.ns + np.arange(rows - n_docs)
+            i = j = 0
+            for s, (st, ts, ds) in enumerate(zip(self._shards, slabs, docs)):
+                k = slice(i, i + len(ts))
+                s_[k, 0], t_[k, 0], ln_[k, 0] = s, ts, st.lengths[ts]
+                p_[k], a_[k] = st.postings[ts], st.attrs[ts]
+                b_[k] = _block_max_rows(st.postings[ts], st.lengths[ts])
+                k = slice(j, j + len(ds))
+                ds_[k, 0], dl_[k, 0] = s, ds
+                df_[k, 0], dsite_[k, 0] = st.doc_flags[ds], st.doc_site[ds]
+                i, j = i + len(ts), j + len(ds)
+            self._publish = _HostPublish(ver, base, None, buf, rows, n_slabs, logs)
+            return self._publish
 
     def host_delta(self) -> ShardedDelta:
-        """Snapshot the host mirrors into a stacked pytree of numpy arrays,
-        the host half of a publish (the caller places it).
+        """The whole snapshot rebuilt from the host mirrors, a stacked
+        pytree of numpy arrays: the full publish, and the oracle a patched
+        device snapshot equals bit for bit.
 
-        Shapes are fixed at construction, so repeated snapshots never
+        Shapes are fixed per generation, so repeated snapshots never
         retrigger compilation of jitted query functions; the snapshot is
         cached per version (mutation batches invalidate it) and its
         arrays are never written again.
         """
-        if self._snapshot is not None and self._snapshot_version == self._version:
+        with self._exclusive():
+            if self._snapshot is not None and self._snapshot_version == self._version:
+                return self._snapshot
+            ns, cap = self.ns, self.term_capacity
+            # TILE-pad the flat arrays (spare INVALID tile included — the
+            # same flat_tile_pad invariant as the main index, so the
+            # streaming kernels can address whole (8, 128) tiles and
+            # clamped edge reads stay provably masked); block_max stays
+            # exact (see DeltaIndex).
+            flat = self.n_terms * cap
+            flat_pad = flat_tile_pad(flat)
+            postings = np.full((ns, flat_pad), INVALID_DOC, np.int32)
+            attrs = np.full((ns, flat_pad), INVALID_ATTR, np.int32)
+            # Skip table: unlike the main index, the max is over *valid*
+            # postings only (a partially-filled block records its true max,
+            # an empty block INVALID_DOC): the device read path uses this
+            # table both for posting skipping and to tell an occupied slab
+            # from an empty one (delta-merge skip).  Only occupied slabs
+            # need the reduction.
+            block_max = np.full(
+                (ns, self.n_terms, cap // BLOCK), INVALID_DOC, np.int32
+            )
+            for s, st in enumerate(self._shards):
+                postings[s, :flat] = st.postings.reshape(-1)
+                attrs[s, :flat] = st.attrs.reshape(-1)
+                ts = np.flatnonzero(st.lengths)
+                block_max[s, ts] = _block_max_rows(st.postings[ts], st.lengths[ts])
+            offsets = np.broadcast_to(
+                (np.arange(self.n_terms, dtype=np.int32) * cap)[None],
+                (ns, self.n_terms),
+            )
+            self._snapshot = ShardedDelta(
+                offsets=np.ascontiguousarray(offsets),
+                lengths=np.stack([s.lengths for s in self._shards]),
+                postings=postings,
+                attrs=attrs,
+                block_max=block_max.reshape(ns, -1),
+                doc_flags=np.stack([s.doc_flags for s in self._shards]),
+                doc_site=np.stack([s.doc_site for s in self._shards]),
+            )
+            self._snapshot_version = self._version
+            export_index_bytes(int(postings.nbytes), None, kind="delta")
             return self._snapshot
-        ns, cap = self.ns, self.term_capacity
-        lengths = np.stack([s.lengths for s in self._shards])
-        # TILE-pad the flat arrays (spare INVALID tile included — the same
-        # flat_tile_pad invariant as the main index, so the streaming
-        # kernels can address whole (8, 128) tiles and clamped edge reads
-        # stay provably masked); block_max stays exact (see DeltaIndex).
-        flat = self.n_terms * cap
-        flat_pad = flat_tile_pad(flat)
-        postings = np.full((ns, flat_pad), INVALID_DOC, np.int32)
-        attrs = np.full((ns, flat_pad), INVALID_ATTR, np.int32)
-        for s, st in enumerate(self._shards):
-            postings[s, :flat] = st.postings.reshape(-1)
-            attrs[s, :flat] = st.attrs.reshape(-1)
-        # Skip table, computed sparsely: all-padding blocks reduce to
-        # INVALID_DOC, so only occupied term slabs need the max-reduction
-        # (the snapshot sits on the ingest hot path).  Unlike the main
-        # index, the max is over *valid* postings only (a partially-filled
-        # block records its true max, an empty block INVALID_DOC): the
-        # device read path uses this table both for posting skipping and to
-        # tell an occupied slab from an empty one (delta-merge skip).
-        bpt = cap // BLOCK
-        block_max = np.full((ns, self.n_terms * bpt), INVALID_DOC, np.int32)
-        for s, st in enumerate(self._shards):
-            for t in np.flatnonzero(st.lengths):
-                ln = int(st.lengths[t])
-                row = np.where(
-                    np.arange(cap) < ln, st.postings[t], np.int32(-1)
-                ).reshape(bpt, BLOCK).max(axis=1)
-                block_max[s, t * bpt : (t + 1) * bpt] = np.where(
-                    row >= 0, row.astype(np.int32), INVALID_DOC
-                )
-        offsets = np.broadcast_to(
-            (np.arange(self.n_terms, dtype=np.int32) * cap)[None], (ns, self.n_terms)
-        )
-        self._snapshot = ShardedDelta(
-            offsets=np.ascontiguousarray(offsets),
-            lengths=lengths,
-            postings=postings,
-            attrs=attrs,
-            block_max=block_max,
-            doc_flags=np.stack([s.doc_flags for s in self._shards]),
-            doc_site=np.stack([s.doc_site for s in self._shards]),
-        )
-        self._snapshot_version = self._version
-        export_index_bytes(int(postings.nbytes), None, kind="delta")
-        return self._snapshot
 
     def shard_deltas(self) -> list[DeltaIndex]:
         """Per-shard device views (for the sequential reference path).
@@ -700,11 +923,9 @@ class ShardedDeltaWriter(DeltaWriter):
       cross-stream conflict race (e.g. update of a doc another master
       deleted, or a capacity-exhausted insert) is dropped and counted on
       ``odys_ingest_conflicts_total`` instead of poisoning the queue.
-    - :meth:`host_delta` publishes under :meth:`frozen` (all shard locks,
-      re-entrant) and stamps the snapshot with the
-      :class:`VectorVersion` ``(epoch, per-shard seqs)``; per-shard rows
-      are cached by their ``(epoch, seq)`` so a publish recomputes the
-      skip table only for shards that actually moved.
+    - A publish (the base class's, dirty slabs and all) runs under
+      :meth:`frozen` (all shard locks, re-entrant); the snapshot's stamp
+      is the :class:`VectorVersion` ``(epoch, per-shard seqs)``.
 
     Divergence from the single-writer base: a concurrent insert reserves
     its docID *before* the capacity check (the shard is a function of the
@@ -729,6 +950,8 @@ class ShardedDeltaWriter(DeltaWriter):
             term_capacity=term_capacity, doc_headroom=doc_headroom,
             codec=codec,
         )
+        reg = registry if registry is not None else get_registry()
+        self._publish_metrics(reg)
         # Lock order is always alloc -> shard (frozen() follows it too);
         # no path acquires the alloc lock while holding a shard lock.
         self._alloc_lock = threading.RLock()
@@ -738,9 +961,6 @@ class ShardedDeltaWriter(DeltaWriter):
         self._seqs = [0] * ns
         self._queues: list[deque] = [deque() for _ in range(ns)]
         self._rr = itertools.count()          # insert striping cursor
-        # per-shard publish cache: (epoch, seq) -> flattened device rows
-        self._shard_rows: list[tuple | None] = [None] * ns
-        reg = registry if registry is not None else get_registry()
         self._m_ops = {
             op: reg.counter(
                 "odys_ingest_ops_total",
@@ -843,12 +1063,14 @@ class ShardedDeltaWriter(DeltaWriter):
                     # docID already allocated: leave a dead, empty
                     # placeholder so global docIDs stay dense
                     st.doc_flags[local] |= DOC_DEAD
+                    st.doc_log.append(local)
                     self._docs[gid] = np.zeros(0, dtype=np.int32)
                     self._bump(shard)
                     raise DeltaFullError(f"delta list full for term {t}")
             for t in plist:
                 self._insert_posting(st, t, local, site)
             st.doc_site[local] = site
+            st.doc_log.append(local)
             self._delta_docs.add(gid)
             self._bump(shard)
         finally:
@@ -940,76 +1162,14 @@ class ShardedDeltaWriter(DeltaWriter):
     def rebase(self, folded, **kw) -> None:
         with self.frozen():
             super().rebase(folded, **kw)
-            self._shard_rows = [None] * self.ns
 
-    def host_delta(self) -> ShardedDelta:
-        """Publish: snapshot the shard mirrors, stamped with the
-        :class:`VectorVersion`.  Shards whose ``(epoch, seq)`` did not move
-        since the last publish reuse their cached flattened rows (the skip
-        table is the expensive part of a publish)."""
+    def _exclusive(self):
+        return self.frozen()
+
+    def device_delta(self) -> ShardedDelta:
+        """Publish, stamped with the :class:`VectorVersion` it holds."""
         with self.frozen():
-            ver = self.version
-            if (
-                self._snapshot is not None
-                and self._snapshot_version == ver
-            ):
-                return self._snapshot
-            ns, cap = self.ns, self.term_capacity
-            bpt = cap // BLOCK
-            flat = self.n_terms * cap
-            flat_pad = flat_tile_pad(flat)
-            # lint: allow(posting-alloc)
-            postings = np.full((ns, flat_pad), INVALID_DOC, np.int32)
-            # lint: allow(posting-alloc)
-            attrs = np.full((ns, flat_pad), INVALID_ATTR, np.int32)
-            block_max = np.full(
-                (ns, self.n_terms * bpt), INVALID_DOC, np.int32
-            )
-            flags = np.zeros((ns, self.nd_cap), np.int32)
-            sites = np.zeros((ns, self.nd_cap), np.int32)
-            for s, st in enumerate(self._shards):
-                key = (self._epoch, self._seqs[s])
-                cached = self._shard_rows[s]
-                if cached is None or cached[0] != key:
-                    # lint: allow(posting-alloc)
-                    row_p = np.full(flat_pad, INVALID_DOC, np.int32)
-                    # lint: allow(posting-alloc)
-                    row_a = np.full(flat_pad, INVALID_ATTR, np.int32)
-                    row_p[:flat] = st.postings.reshape(-1)
-                    row_a[:flat] = st.attrs.reshape(-1)
-                    row_b = np.full(self.n_terms * bpt, INVALID_DOC, np.int32)
-                    for t in np.flatnonzero(st.lengths):
-                        ln = int(st.lengths[t])
-                        row = np.where(
-                            np.arange(cap) < ln, st.postings[t], np.int32(-1)
-                        ).reshape(bpt, BLOCK).max(axis=1)
-                        row_b[t * bpt : (t + 1) * bpt] = np.where(
-                            row >= 0, row.astype(np.int32), INVALID_DOC
-                        )
-                    cached = (
-                        key, row_p, row_a, row_b,
-                        st.doc_flags.copy(), st.doc_site.copy(),
-                    )
-                    self._shard_rows[s] = cached
-                postings[s] = cached[1]
-                attrs[s] = cached[2]
-                block_max[s] = cached[3]
-                flags[s] = cached[4]
-                sites[s] = cached[5]
-                self._m_publish[s].set(float(self._seqs[s]))
-            offsets = np.broadcast_to(
-                (np.arange(self.n_terms, dtype=np.int32) * cap)[None],
-                (ns, self.n_terms),
-            )
-            self._snapshot = ShardedDelta(
-                offsets=np.ascontiguousarray(offsets),
-                lengths=np.stack([s.lengths for s in self._shards]),
-                postings=postings,
-                attrs=attrs,
-                block_max=block_max,
-                doc_flags=flags,
-                doc_site=sites,
-            )
-            self._snapshot_version = ver
-            export_index_bytes(int(postings.nbytes), None, kind="delta")
-            return self._snapshot
+            snap = super().device_delta()
+            for s, seq in enumerate(self._seqs):
+                self._m_publish[s].set(float(seq))
+            return snap

@@ -29,14 +29,17 @@ their parent (a child's time is part of its parent's):
   - ``delta_publish``  — only on a batch whose delta snapshot version
     differs from the placed one:
 
-    - ``delta_rebuild`` — ``DeltaWriter.host_delta``, the numpy snapshot
-      of the writer's mirrors;
-    - ``delta_place``   — ``DeltaWriter.device_delta`` and ``device_put``
-      of that snapshot on the mesh.  No host sync is added to wait for
-      the transfer: the phase times the placement calls, which return
-      once the runtime has taken the host arrays (on a TPU v5e that is
-      after their host-to-device copy, whose events lie on the runtime's
-      threads inside the phase);
+    - ``delta_rebuild`` — ``DeltaWriter.host_publish``, the host gather:
+      the term slabs and documents written since the writer's placed
+      snapshot, packed into one patch buffer (on the writer's first
+      publish, after a rebase, or past the last patch bucket, the whole
+      ``DeltaWriter.host_delta`` snapshot instead);
+    - ``delta_place``   — ``DeltaWriter.device_delta``: the patch's
+      host-to-device copy and the dispatch of its scatter into a copy of
+      the placed snapshot (or the full snapshot's placement), then
+      ``device_put`` onto the mesh.  No host sync is added: the phase
+      times the calls, which return once the runtime has taken the host
+      arrays;
 
   - ``launch``         — the call of the jitted
     ``distributed_query_topk``/``replicated_query_topk`` until it returns;

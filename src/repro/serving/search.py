@@ -330,11 +330,12 @@ class SearchService:
         """Current delta snapshot placed on ``set_id``'s slice (None: the
         service mesh), cached per (placement, writer version) — a new
         publish on any shard re-places.  A publish is the batch's
-        ``delta_publish`` phase: the writer's host snapshot
-        (``delta_rebuild``), then its placement through
-        ``writer.device_delta`` and onto the mesh (``delta_place``: no sync
-        is added; the placement calls return once the runtime has taken
-        the host arrays)."""
+        ``delta_publish`` phase: the writer's host gather of what changed
+        since its placed snapshot (``delta_rebuild``), then
+        ``writer.device_delta`` — the patch's copy to the device and its
+        scatter into a copy of that snapshot, or a full placement — and
+        the ``device_put`` onto the mesh, which on a one-device mesh keeps
+        the writer's buffers (``delta_place``: no sync is added)."""
         if self.writer is None:
             return None
         # read before the snapshot: a mutation racing the publish can only
@@ -346,7 +347,7 @@ class SearchService:
         if clock is not None:
             clock.open("delta_publish")
             clock.open("delta_rebuild")
-        self.writer.host_delta()        # cached for device_delta below
+        self.writer.host_publish()      # cached for device_delta below
         if clock is not None:
             clock.close("delta_rebuild")
             clock.open("delta_place")
