@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import resource
 import shutil
 import sys
 import tempfile
@@ -45,7 +46,7 @@ class Batch:
     t_start: float
     t_end: float
     traced: bool
-    drivers: list | None = None  # (base, delta) driver lengths per query
+    drivers: list | None = None  # per query, each slave's (base, delta) driver lengths
 
 
 @dataclasses.dataclass
@@ -112,6 +113,7 @@ def build(cell: spec.Cell, *, seed: int, trace: bool, service_hook=None) -> Buil
     from repro.obs.registry import MetricsRegistry
     from repro.serving.search import SearchService
 
+    spec.check_cell(cell.config, cell.chips)
     cfg, svc_cfg = cell.config, cell.config["service"]
     ingest = cfg.get("ingest")
     setup = {}
@@ -153,6 +155,8 @@ def build(cell: spec.Cell, *, seed: int, trace: bool, service_hook=None) -> Buil
         svc.submit(list(q.terms), q.site, k=q.k)
     svc.drain()
     setup["warmup_s"] = time.perf_counter() - t
+    peak_kib = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
+    log(f"set-up: host peak resident {peak_kib} KiB")
     return Built(svc, host, traffic, setup)
 
 
@@ -163,28 +167,29 @@ def measure(cell: spec.Cell, built: Built, *, seed: int, seconds: float,
     loop = _open_loop if built.traffic.loop == "open" else _closed_loop
     res = loop(built.svc, built.traffic, seed=seed, seconds=seconds,
                trace=trace, t_process=t_process)
-    dev = jax.devices()[0]
-    mem = dev.memory_stats() or {}
-    device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": cell.chips,
-              "memory_peak_bytes": int(mem.get("peak_bytes_in_use", 0))}
+    devs = jax.devices()[:cell.chips]
+    peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0)) for d in devs]
+    log(f"device peaks (B), chip by chip: {peaks}")
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": cell.chips, "memory_peak_bytes": max(peaks)}
     return res, device
 
 
 def verify(cell: spec.Cell, res: dict, host: bench_corpus.HostCorpus, *,
            window: int, mutations: bool = True) -> int:
     """Check every answer the run served against the reference at
-    ``window`` (without ``mutations``: over the crawl as built, ignoring
-    the acknowledged mutations); returns how many differ, and records each
-    batch's driver lengths on it."""
+    ``window`` over the configuration's set of ``ns`` slaves (without
+    ``mutations``: over the crawl as built, ignoring the acknowledged
+    mutations); returns how many differ, and records each batch's driver
+    lengths on it."""
     t = time.perf_counter()
     served = [(b.n_applied, b.answers) for b in res["batches"]]
-    lists = reference.PostingLists(
+    slaves = reference.slave_lists(
         host.doc_offsets, host.doc_terms, host.doc_site, host.shape.vocab_size,
         terms={t for _, rows in served for q, _, _ in rows for t in q.terms},
-        window=window)
+        window=window, ns=cell.config["service"]["ns"])
     n_checked, n_bad, drivers = reference.check(
-        lists, served, res["mutations"], window=window,
+        slaves, served, res["mutations"], window=window,
         ingest=mutations and cell.config.get("ingest") is not None)
     for b, d in zip(res["batches"], drivers):
         b.drivers = d

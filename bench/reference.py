@@ -24,6 +24,24 @@ List lengths, which choose the driver, are base plus delta postings.
 
 With the window covering every list, both forms give the exact answer of
 a rebuilt index over the mutated crawl.
+
+A *set* of ``ns`` slaves (ODYS secs. 3 and 5.1: the master broadcasts a
+query, each slave answers over its own documents, the master merges)
+answers as follows (``core/parallel.py``, ``partition_corpus`` in
+``core/index.py``):
+
+- slave ``s`` holds the global documents ``d`` with ``d % ns == s``, as
+  local documents ``d // ns`` in the same order; its lists, their lengths
+  and their first ``window`` postings are its own;
+- each slave answers the query as above over its own lists: it picks its
+  own driver from its local list lengths and reads its own first
+  ``window`` postings of each keyword; its survivors map back to global
+  docIDs as ``local * ns + s``;
+- ``n_hits`` is the sum of the slaves' counts, and the answer is the first
+  ``k`` of the union of the slaves' survivors, by global docID.
+
+With ``ns`` 1 the set is the one slave above, and the set's answer is that
+slave's.  Online ingest is answered for one slave only.
 """
 from __future__ import annotations
 
@@ -92,6 +110,29 @@ class PostingLists:
         if window > self.window:
             raise ValueError(f"lists were read with window {self.window}")
         return self._heads[t][:window]
+
+
+def stripe(doc_offsets: np.ndarray, doc_terms: np.ndarray, doc_site: np.ndarray,
+           shard: int, ns: int) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Slave ``shard``'s documents of a set of ``ns``: the crawl's documents
+    ``shard, shard + ns, ...`` in order, as a crawl of their own (CSR
+    offsets, terms, sites); with ``ns`` 1, the crawl itself."""
+    if ns == 1:
+        return doc_offsets, doc_terms, doc_site
+    starts = doc_offsets[:-1][shard::ns]
+    lens = doc_offsets[1:][shard::ns] - starts
+    offsets = np.zeros(lens.shape[0] + 1, np.int64)
+    np.cumsum(lens, out=offsets[1:])
+    take = np.repeat(starts - offsets[:-1], lens) + np.arange(offsets[-1])
+    return offsets, doc_terms[take], doc_site[shard::ns]
+
+
+def slave_lists(doc_offsets, doc_terms, doc_site, vocab_size: int, *,
+                terms: Iterable[int], window: int, ns: int) -> list[PostingLists]:
+    """Each slave's :class:`PostingLists` over its stripe of the crawl."""
+    terms = set(terms)
+    return [PostingLists(*stripe(doc_offsets, doc_terms, doc_site, s, ns), vocab_size,
+                         terms=terms, window=window) for s in range(ns)]
 
 
 class Ingest:
@@ -183,17 +224,35 @@ def answer(lists: PostingLists, ingest: Ingest | None, terms, site, k: int,
             int(lists.lengths[terms[d]]), int(dl[terms[d]].shape[0]))
 
 
-def check(lists: PostingLists, batches: Iterable, mutations: list, *,
+def set_answer(slaves: list[PostingLists], ingest: Ingest | None, terms, site,
+               k: int, window: int) -> tuple[list[int], int, tuple]:
+    """(docids, n_hits) of one query over a set of slaves by the semantics
+    above, and each slave's driver base and delta list lengths."""
+    ns = len(slaves)
+    if ingest is not None and ns > 1:
+        raise ValueError("online ingest is answered for one slave only")
+    docids, n_hits, drivers = [], 0, []
+    for s, lists in enumerate(slaves):
+        local, n, base, delta = answer(lists, ingest, terms, site, k, window)
+        docids += [x * ns + s for x in local]
+        n_hits += n
+        drivers.append((base, delta))
+    return sorted(docids)[:k], n_hits, tuple(drivers)
+
+
+def check(slaves: list[PostingLists], batches: Iterable, mutations: list, *,
           window: int, ingest: bool) -> tuple[int, int, list]:
-    """Compare every served answer with the reference's.
+    """Compare every served answer with the reference's over the set of
+    ``slaves`` (one :class:`PostingLists` a slave, in slave order).
 
     ``batches`` holds ``(n_applied, [(query, docids, n_hits), ...])`` in
     dispatch order: the answers one batch served after the first
     ``n_applied`` mutations were acknowledged.  Returns the queries
-    compared, the queries that differ, and per batch the driver lengths
-    ``(base, delta)`` of each query (the roofline's byte count reads them).
+    compared, the queries that differ, and per batch, per query, each
+    slave's driver lengths ``(base, delta)`` (the rooflines' byte counts
+    read them).
     """
-    state = Ingest(lists) if ingest else None
+    state = Ingest(slaves[0]) if ingest else None
     memo: dict = {}      # read-only lists: a repeated query has one answer
     applied, n, bad, drivers = 0, 0, 0, []
     for n_applied, served in batches:
@@ -205,11 +264,11 @@ def check(lists: PostingLists, batches: Iterable, mutations: list, *,
             key = (q.terms, q.site, q.k)
             want = memo.get(key) if state is None else None
             if want is None:
-                want = answer(lists, state, q.terms, q.site, q.k, window)
+                want = set_answer(slaves, state, q.terms, q.site, q.k, window)
                 if state is None:
                     memo[key] = want
             n += 1
             bad += (list(docids), int(n_hits)) != tuple(want[:2])
-            lens.append(want[2:])
+            lens.append(want[2])
         drivers.append(lens)
     return n, bad, drivers

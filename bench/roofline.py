@@ -19,6 +19,12 @@ slots do no work a user asked for):
   written once: its docIDs and ``n_hits``.
 - the delta merge: the driver's base window and its delta list, read once
   (postings, and attrs when site-limited).
+
+In a set of ``ns`` slaves each slave reads its own driver window (or, for
+a lone keyword, its own first ``k``) from its own lists, so the readers sum
+the reads over the slaves; the answer is still counted once.  The device
+time (``trace.kernel_ns``) is summed over every chip's plane too, so the
+share stays a share of one chip's peak.
 """
 from __future__ import annotations
 
@@ -46,15 +52,18 @@ def driver_window(base_len: int, delta_len: int, window: int) -> int:
     return min(min(base_len, window) + delta_len, window)
 
 
-def intersect_bytes(*, base_len: int, delta_len: int, n_terms: int,
-                    limited: bool, merged: bool, k: int, n_answer: int,
-                    window: int) -> int:
+def intersect_read_bytes(*, base_len: int, delta_len: int, n_terms: int,
+                         limited: bool, merged: bool, k: int, window: int) -> int:
+    """One slave's driver read."""
     n_eff = driver_window(base_len, delta_len, window)
     if n_terms == 1 and not limited and not merged:
-        read = min(k, n_eff) * WORD
-    else:
-        read = n_eff * WORD * (2 if limited else 1)
-    return read + (n_answer + 1) * WORD
+        return min(k, n_eff) * WORD
+    return n_eff * WORD * (2 if limited else 1)
+
+
+def answer_bytes(n_answer: int) -> int:
+    """The answer's docIDs and its ``n_hits``."""
+    return (n_answer + 1) * WORD
 
 
 def delta_merge_bytes(*, base_len: int, delta_len: int, limited: bool,

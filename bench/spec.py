@@ -27,6 +27,19 @@ def _reports(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
+def check_cell(config: dict, chips: int) -> None:
+    """Refuse a cell the harness cannot judge: a set whose slaves are not
+    one a chip (ODYS deploys a slave a node), and online ingest into a set
+    of more than one slave (the reference answers ingest for one slave)."""
+    ns = config["service"]["ns"]
+    if ns != chips:
+        raise ValueError(f"a set of ns={ns} slaves on {chips} chips: the benchmark "
+                         "runs one slave a chip")
+    if ns > 1 and config.get("ingest") is not None:
+        raise ValueError(f"online ingest into a set of ns={ns} slaves: the reference "
+                         "answers ingest for one slave only")
+
+
 def load_cell(name: str, root: Path = ROOT) -> Cell:
     spec = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in spec["workloads"]}
@@ -40,6 +53,7 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     reported = {m["name"] for m in e2e}
     layer = [m for m in spec["per_layer"]
              if _reports(m, name) and m["moves"] in reported]
+    check_cell(config, int(w["chips"]))
     return Cell(name, config, traffic, int(w["chips"]), e2e, layer)
 
 

@@ -17,6 +17,15 @@ A mix file holds:
   Zipf(``site_zipf_s``) sites, and ``p_site_change`` of updates moving site;
   deletes and updates pick a live page Zipf(``target_zipf_s``) over docIDs
   (rank order, so the popular pages change most; 0 is uniform);
+- ``arrivals`` (open loop, optional): ``"poisson"`` (the default: each
+  window's count of arrivals uniform in it, a Poisson stream given its
+  count) or ``"fixed_set"``: every seed gets the same set of gaps between
+  queries (the quantiles of the exponential gap at the query rate), in an
+  order drawn from the seed, and each mutation the same set of waits
+  until the next query arrives (the quantiles of the exponential
+  residual, as a Poisson stream independent of the queries would give),
+  placed at a time drawn from the seed; so a tail over the mutations'
+  waits reads the same work on every seed;
 - ``assumed``: the reason for each value, read by no code.
 
 Queries draw keywords as ODYS's workload generator does (Zipf over term
@@ -93,6 +102,8 @@ class Traffic:
         self.mut = spec.get("mutations")
         if self.mut is not None and self.loop != "open":
             raise ValueError("a mutation stream needs an open loop")
+        if spec.get("arrivals", "poisson") not in ("poisson", "fixed_set"):
+            raise ValueError(f"arrivals must be poisson or fixed_set, got {spec['arrivals']!r}")
 
     # -- queries -------------------------------------------------------
 
@@ -135,10 +146,15 @@ class Traffic:
             raise ValueError("only an open loop has a schedule")
         q_rng, m_rng = (np.random.default_rng(s) for s in
                         np.random.SeedSequence(seed).spawn(2))
-        events = [Event(t, query=self.query(q_rng))
-                  for t in _arrivals(q_rng, float(self.spec["rate_qps"]), seconds, tail)]
+        rate = float(self.spec["rate_qps"])
+        fixed = self.spec.get("arrivals", "poisson") == "fixed_set"
+        q_times = (_fixed_arrivals(q_rng, rate, seconds, tail) if fixed
+                   else _arrivals(q_rng, rate, seconds, tail))
+        events = [Event(t, query=self.query(q_rng)) for t in q_times]
         if self.mut is not None:
-            times = _arrivals(m_rng, float(self.mut["rate_per_s"]), seconds, tail)
+            m_rate = float(self.mut["rate_per_s"])
+            times = (_fixed_waits(m_rng, m_rate, rate, q_times, seconds, tail) if fixed
+                     else _arrivals(m_rng, m_rate, seconds, tail))
             events += [Event(t, mutation=m)
                        for t, m in zip(times, self.mutations(m_rng, len(times)))]
         events.sort(key=lambda e: e.t)
@@ -197,3 +213,51 @@ def _arrivals(rng, rate: float, seconds: float, tail: float) -> list[float]:
     head = rng.uniform(0.0, seconds, size=int(round(rate * seconds)))
     rest = seconds + rng.uniform(0.0, tail, size=int(round(rate * tail)))
     return [float(x) for x in np.sort(np.concatenate([head, rest]))]
+
+
+def _exp_quantiles(rate: float, n: int) -> np.ndarray:
+    """The ``n`` quantiles of an exponential of ``rate`` at ``(i + 0.5) / n``."""
+    return -np.log1p(-(np.arange(n) + 0.5) / n) / rate
+
+
+def _fixed_arrivals(rng, rate: float, seconds: float, tail: float) -> list[float]:
+    """As ``_arrivals``, the same counts, but every seed the same set of
+    gaps: in each span (the window, the tail) of ``n`` arrivals, ``n + 1``
+    exponential quantiles in an order drawn from ``rng``, scaled to fill
+    the span, the last gap running to its end."""
+    out: list[float] = []
+    for start, span in ((0.0, seconds), (seconds, tail)):
+        n = int(round(rate * span))
+        if n:
+            g = rng.permutation(_exp_quantiles(rate, n + 1))
+            out += [float(x) for x in start + np.cumsum(g * (span / g.sum()))[:-1]]
+    return out
+
+
+def _fixed_waits(rng, rate: float, q_rate: float, q_times: list[float],
+                 seconds: float, tail: float) -> list[float]:
+    """``round(rate * span)`` mutation times in each span (the window, the
+    tail), sorted, each one's wait until the next query arrival taken from
+    the span's exponential quantiles at the query rate, in an order drawn
+    from ``rng``.  A mutation with wait ``r`` and a time drawn uniform in the
+    span goes before the first query of the span at or after that time
+    whose gap from the query before it (or from the span's start) is over
+    ``r``; where there is none, before the last such query."""
+    q = np.asarray(q_times)
+    out: list[float] = []
+    for start, span in ((0.0, seconds), (seconds, tail)):
+        n = int(round(rate * span))
+        if not n:
+            continue
+        a = q[(q >= start) & (q < start + span)]
+        gaps = np.diff(a, prepend=start)
+        waits = rng.permutation(_exp_quantiles(q_rate, n))
+        anchors = rng.uniform(start, start + span, size=n)
+        for r, u in zip(waits, anchors):
+            fits = np.flatnonzero(gaps > r)
+            if not fits.size:
+                raise ValueError(f"no gap between queries holds a wait of {r:.3f} s: "
+                                 "fixed_set needs fewer mutations than queries")
+            after = fits[a[fits] - r >= u]
+            out.append(float(a[after[0] if after.size else fits[-1]] - r))
+    return sorted(out)

@@ -28,16 +28,17 @@ def test_share_cannot_pass_peak_when_time_covers_bytes():
 
 
 def test_bytes_by_condition():
-    kw = dict(base_len=10_000, delta_len=0, k=10, n_answer=10, window=4096)
-    single = roofline.intersect_bytes(n_terms=1, limited=False, merged=False, **kw)
-    multi = roofline.intersect_bytes(n_terms=2, limited=False, merged=False, **kw)
-    limited = roofline.intersect_bytes(n_terms=1, limited=True, merged=False, **kw)
-    assert single == 4 * 10 + 4 * 11
-    assert multi == 4 * 4096 + 4 * 11
-    assert limited == 8 * 4096 + 4 * 11
-    merged = roofline.intersect_bytes(n_terms=1, limited=False, merged=True,
-                                      **dict(kw, delta_len=5))
-    assert merged == 4 * 4096 + 4 * 11
+    kw = dict(base_len=10_000, delta_len=0, k=10, window=4096)
+    single = roofline.intersect_read_bytes(n_terms=1, limited=False, merged=False, **kw)
+    multi = roofline.intersect_read_bytes(n_terms=2, limited=False, merged=False, **kw)
+    limited = roofline.intersect_read_bytes(n_terms=1, limited=True, merged=False, **kw)
+    assert roofline.answer_bytes(10) == 4 * 11
+    assert single == 4 * 10
+    assert multi == 4 * 4096
+    assert limited == 8 * 4096
+    merged = roofline.intersect_read_bytes(n_terms=1, limited=False, merged=True,
+                                           **dict(kw, delta_len=5))
+    assert merged == 4 * 4096
     assert roofline.delta_merge_bytes(base_len=100, delta_len=7, limited=False,
                                       window=4096) == 4 * 107
 
@@ -46,13 +47,14 @@ def _batch_bytes(lists, queries, docs, hits, window) -> int:
     """The intersect reader's byte sum over one batch's answers."""
     served = [(0, [(q, [int(x) for x in row if x != INVALID_DOC], int(h))
                    for q, row, h in zip(queries, docs, hits)])]
-    n, bad, drivers = reference.check(lists, served, [], window=window, ingest=False)
+    n, bad, drivers = reference.check([lists], served, [], window=window, ingest=False)
     assert (n, bad) == (len(queries), 0)
     return sum(
-        roofline.intersect_bytes(base_len=base, delta_len=delta, n_terms=len(q.terms),
-                                 limited=q.site is not None, merged=False, k=q.k,
-                                 n_answer=len(a), window=window)
-        for (q, a, _), (base, delta) in zip(served[0][1], drivers[0]))
+        roofline.intersect_read_bytes(base_len=base, delta_len=delta,
+                                      n_terms=len(q.terms), limited=q.site is not None,
+                                      merged=False, k=q.k, window=window)
+        + roofline.answer_bytes(len(a))
+        for (q, a, _), ((base, delta),) in zip(served[0][1], drivers[0]))
 
 
 def test_bytes_do_not_depend_on_the_read_path():

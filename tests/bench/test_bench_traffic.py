@@ -39,6 +39,78 @@ def test_open_loop_mean_rate(rate):
         assert np.all(np.abs(q - rate * seconds / 4) < 5 * np.sqrt(rate * seconds / 4))
 
 
+def _poisson_oracle(t: Traffic, seed: int, seconds: float, tail: float):
+    """The open loop's schedule as it was before ``arrivals`` existed."""
+    q_rng, m_rng = (np.random.default_rng(s) for s in
+                    np.random.SeedSequence(seed).spawn(2))
+
+    def arrivals(rng, rate):
+        head = rng.uniform(0.0, seconds, size=int(round(rate * seconds)))
+        rest = seconds + rng.uniform(0.0, tail, size=int(round(rate * tail)))
+        return [float(x) for x in np.sort(np.concatenate([head, rest]))]
+
+    events = [(tt, t.query(q_rng), None) for tt in arrivals(q_rng, t.spec["rate_qps"])]
+    if t.mut is not None:
+        times = arrivals(m_rng, t.mut["rate_per_s"])
+        events += [(tt, None, m) for tt, m in zip(times, t.mutations(m_rng, len(times)))]
+    return sorted(events, key=lambda e: e[0])
+
+
+@pytest.mark.parametrize("name", ["mix-open", "mix-open-ingest"])
+def test_poisson_schedule_is_the_old_one(name):
+    # "poisson", the default, draws exactly what was drawn before the key
+    t = traffic(name, arrivals="poisson")
+    got = [(e.t, e.query, e.mutation) for e in t.schedule(2**33 + 3, 30.0, tail=5.0)]
+    assert got == _poisson_oracle(t, 2**33 + 3, 30.0, 5.0)
+    assert "arrivals" not in traffic("mix-open").spec
+
+
+def _waits(events, seconds):
+    q = np.asarray([e.t for e in events if e.query is not None])
+    m = [e.t for e in events if e.mutation is not None and e.t < seconds]
+    return np.sort([q[q > x][0] - x for x in m]) if m else np.zeros(0)
+
+
+def test_fixed_set_gives_every_seed_the_same_waits():
+    t = traffic("mix-open-ingest")
+    assert t.spec["arrivals"] == "fixed_set"
+    seconds, tail = 51.0, 5.0
+    runs = [t.schedule(seed, seconds, tail=tail) for seed in (7, 2**31 + 11, 2**40 + 3)]
+    rate, m_rate = t.spec["rate_qps"], t.mut["rate_per_s"]
+    gaps, waits = [], []
+    for events in runs:
+        assert [e.t for e in events] == sorted(e.t for e in events)
+        q = [e.t for e in events if e.query is not None]
+        m = [e.t for e in events if e.mutation is not None]
+        assert sum(x < seconds for x in q) == round(rate * seconds)
+        assert len(q) == round(rate * (seconds + tail))
+        assert sum(x < seconds for x in m) == round(m_rate * seconds)
+        assert len(m) == round(m_rate * (seconds + tail))
+        assert all(0 <= x < seconds + tail for x in q + m)
+        gaps.append(np.sort(np.diff([x for x in q if x < seconds],
+                                    prepend=0.0, append=seconds)))
+        waits.append(_waits(events, seconds))
+    for g, w in zip(gaps[1:], waits[1:]):
+        np.testing.assert_allclose(g, gaps[0], rtol=1e-9, atol=1e-9)
+        np.testing.assert_allclose(w, waits[0], rtol=1e-9, atol=1e-9)
+    # the waits are the exponential residual's quantiles at the query rate
+    n = round(m_rate * seconds)
+    want = -np.log1p(-(np.arange(n) + 0.5) / n) / rate
+    np.testing.assert_allclose(waits[0], want, rtol=1e-9, atol=1e-9)
+    # the seeds still change the order and the draws
+    assert [e.t for e in runs[0]] != [e.t for e in runs[1]]
+    assert [e.query for e in runs[0] if e.query] != [e.query for e in runs[1] if e.query]
+
+
+@pytest.mark.parametrize("over,match", [
+    ({"arrivals": "uniform"}, "arrivals must be"),
+    ({"rate_qps": 1.0}, "fewer mutations than queries"),
+])
+def test_fixed_set_refuses_what_it_cannot_draw(over, match):
+    with pytest.raises(ValueError, match=match):
+        traffic("mix-open-ingest", **over).schedule(5, 20.0, tail=2.0)
+
+
 def test_mix_shares_and_query_shapes():
     t = traffic("mix-open", rate_qps=500.0)
     qs = [e.query for e in t.schedule(3, 40.0)]
