@@ -18,7 +18,7 @@ import jax                                    # noqa: E402
 from repro.core.engine import make_query_batch                    # noqa: E402
 from repro.core.faults import SpeculationPolicy, query_latency_with_speculation  # noqa: E402
 from repro.core.index import INVALID_DOC, build_sharded_index     # noqa: E402
-from repro.core.parallel import distributed_query_topk            # noqa: E402
+from repro.core.parallel import distributed_query_topk, place_index  # noqa: E402
 from repro.core.perfmodel import QUERY_MIX_DEFAULT                # noqa: E402
 from repro.core.queries import WorkloadConfig, batch_by_k, generate_workload  # noqa: E402
 from repro.core.slave_max import partitioning_method              # noqa: E402
@@ -34,6 +34,7 @@ def main():
         CorpusConfig(n_docs=8_000, vocab_size=1_200, mean_doc_len=50, n_sites=40)
     )
     sharded, meta = build_sharded_index(corpus, ns)
+    sharded = place_index(sharded, mesh)      # each slave's shard on its device
     print(f"[demo] {ns} slaves x {corpus.n_docs // ns} docs each")
 
     # workload

@@ -45,6 +45,7 @@ from repro.core.index import INVALID_DOC
 from repro.core.parallel import (
     _row_topk,
     distributed_query_topk,
+    place_index,
     slave_topk_unmerged,
 )
 from repro.core.perfmodel import (
@@ -273,6 +274,7 @@ def calibrate_from_engine(
     Table 3 ratios and marked by their absence from ``st_master``.
     """
     assert 10 in k_values, "the unit query (k=10) must be measured"
+    index = place_index(index, mesh)    # timed calls must not re-ship the index
     t_cmp, t_base, _ = fit_merge_constants(
         k_values=k_values, q=q, reps=reps, backend=backend,
         interpret=interpret, seed=seed,

@@ -43,6 +43,9 @@ TPU-native layout (all dense, HBM-resident):
 from __future__ import annotations
 
 import dataclasses
+import os
+import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import NamedTuple
 
 import numpy as np
@@ -396,6 +399,10 @@ class IndexMeta:
     n_sites: int
     n_terms: int           # vocab_size (+ n_sites when site terms included)
     include_site_terms: bool
+    #: Seconds each shard's host build took (``build_sharded_index``), for
+    #: the serving layer's ``odys_index_shard_build_seconds``; not part of
+    #: what the metadata says about the index, so never compared.
+    shard_build_s: tuple[float, ...] = dataclasses.field(default=(), compare=False)
 
 
 def site_term_id(meta: IndexMeta, site: int) -> int:
@@ -538,37 +545,38 @@ def partition_corpus(corpus: Corpus, ns: int) -> list[Corpus]:
     which is what makes elastic re-partitioning a pure reshuffle
     (launch/elastic.py).
     """
-    shards = []
-    for s in range(ns):
-        sel = np.arange(s, corpus.n_docs, ns)
-        lens = np.diff(corpus.doc_offsets)[sel]
-        offs = np.zeros(sel.shape[0] + 1, dtype=np.int64)
-        np.cumsum(lens, out=offs[1:])
-        gather = np.concatenate(
-            [
-                corpus.doc_terms[corpus.doc_offsets[d]:corpus.doc_offsets[d + 1]]
-                for d in sel
-            ]
-        ) if sel.size else np.zeros(0, dtype=np.int32)
-        shards.append(
-            Corpus(
-                doc_offsets=offs,
-                doc_terms=gather,
-                doc_site=corpus.doc_site[sel],
-                n_docs=int(sel.shape[0]),
-                vocab_size=corpus.vocab_size,
-                n_sites=corpus.n_sites,
-            )
-        )
-    return shards
+    return [stripe_corpus(corpus, s, ns) for s in range(ns)]
+
+
+def stripe_corpus(corpus: Corpus, shard: int, ns: int) -> Corpus:
+    """Shard ``shard`` of :func:`partition_corpus`: the documents
+    ``d % ns == shard`` in docID order, their terms gathered with one
+    index computation."""
+    sel = np.arange(shard, corpus.n_docs, ns)
+    starts = corpus.doc_offsets[sel]
+    lens = corpus.doc_offsets[sel + 1] - starts
+    offs = np.zeros(sel.shape[0] + 1, dtype=np.int64)
+    np.cumsum(lens, out=offs[1:])
+    # term j of the stripe is term (j - offs[i]) of its document i
+    src = np.repeat(starts - offs[:-1], lens) + np.arange(offs[-1])
+    return Corpus(
+        doc_offsets=offs,
+        doc_terms=corpus.doc_terms[src],
+        doc_site=corpus.doc_site[sel],
+        n_docs=int(sel.shape[0]),
+        vocab_size=corpus.vocab_size,
+        n_sites=corpus.n_sites,
+    )
 
 
 class ShardedIndex(NamedTuple):
     """ns stacked per-shard indexes, padded to common shapes.
 
-    Leading axis = shard; intended to be laid out over the mesh ``data``
-    axis (one shard per "slave").  Plus the static local->global docID map
-    parameters (ns, shard id) applied at merge time.
+    Leading axis = shard; laid out over the mesh ``data`` axis, one shard
+    per "slave" (:func:`repro.core.parallel.place_index`).  Plus the static
+    local->global docID map parameters (ns, shard id) applied at merge
+    time.  Leaves are host (NumPy) arrays as built, device arrays once
+    placed.
     """
 
     offsets: jnp.ndarray    # int32[ns, n_terms]
@@ -582,13 +590,35 @@ class ShardedIndex(NamedTuple):
 def build_sharded_index(
     corpus: Corpus, ns: int, *, include_site_terms: bool = True
 ) -> tuple[ShardedIndex, IndexMeta]:
-    parts = partition_corpus(corpus, ns)
-    built = [_build_numpy(p, include_site_terms) for p in parts]
-    metas = [m for _, m in built]
-    arrays = [a for a, _ in built]
+    """Stripe ``corpus`` over ``ns`` shards and build each on the host.
+
+    The shards build concurrently, a thread each up to the host's cores
+    (NumPy's sorts and gathers release the GIL).  The stack stays on the
+    host, so placing it sends each shard's rows straight to its own device
+    and no device ever holds the whole stack.  Traced, the build is an
+    ``odys.index_build`` annotation around one ``odys.shard_build`` (stat
+    ``shard``) a shard.
+    """
+    from repro.obs.trace import annotation
+
+    def build(shard: int):
+        with annotation("shard_build", shard=shard):
+            t0 = time.perf_counter()
+            part = corpus if ns == 1 else stripe_corpus(corpus, shard, ns)
+            out = _build_numpy(part, include_site_terms)
+            return out, time.perf_counter() - t0
+
+    with annotation("index_build"):
+        if ns == 1:
+            built = [build(0)]
+        else:
+            with ThreadPoolExecutor(max_workers=min(ns, os.cpu_count() or 1)) as pool:
+                built = list(pool.map(build, range(ns)))
+    metas = [m for (_, m), _ in built]
+    arrays = [a for (a, _), _ in built]
 
     def stack(key: str, pad_value) -> np.ndarray:
-        ms = [a[key] for a in arrays]
+        ms = [a.pop(key) for a in arrays]   # each shard's copy goes once stacked
         width = max(m.shape[0] for m in ms)
         # keep the per-shard alignment of the padded width: postings/attrs
         # stay TILE-aligned (the streaming kernels read them tile-wise;
@@ -606,12 +636,12 @@ def build_sharded_index(
         return out
 
     sharded = ShardedIndex(
-        offsets=jnp.asarray(stack("offsets", 0)),
-        lengths=jnp.asarray(stack("lengths", 0)),
-        postings=jnp.asarray(stack("postings", INVALID_DOC)),
-        attrs=jnp.asarray(stack("attrs", INVALID_ATTR)),
-        block_max=jnp.asarray(stack("block_max", INVALID_DOC)),
-        doc_site=jnp.asarray(stack("doc_site", INVALID_ATTR)),
+        offsets=stack("offsets", 0),
+        lengths=stack("lengths", 0),
+        postings=stack("postings", INVALID_DOC),
+        attrs=stack("attrs", INVALID_ATTR),
+        block_max=stack("block_max", INVALID_DOC),
+        doc_site=stack("doc_site", INVALID_ATTR),
     )
     meta = IndexMeta(
         n_docs=corpus.n_docs,
@@ -619,6 +649,7 @@ def build_sharded_index(
         n_sites=corpus.n_sites,
         n_terms=metas[0].n_terms,
         include_site_terms=include_site_terms,
+        shard_build_s=tuple(t for _, t in built),
     )
     return sharded, meta
 

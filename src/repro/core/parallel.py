@@ -45,6 +45,12 @@ Mesh mapping (DESIGN.md §2):
   independent replica engine; the query stream is sharded across pods and
   no collective crosses them on the query path (see
   :func:`replicated_query_topk`).
+
+Named scopes: each slave's local top-k (its join and the local->global
+docID map) runs under ``jax.named_scope(SLAVE_SCOPE)``, the master merge
+(the ppermute/all-gather exchange, its top-k merges) and the ``psum`` of
+``n_hits`` under ``jax.named_scope(MERGE_SCOPE)``, so a profiler shows the
+set's work by name (the ops' ``tf_op`` metadata).
 """
 from __future__ import annotations
 
@@ -54,7 +60,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.engine import QueryBatch, query_topk
 from repro.core.index import (
@@ -65,6 +71,10 @@ from repro.core.index import (
 from repro.indexing.delta import DeltaIndex, ShardedDelta, local_delta
 
 
+SLAVE_SCOPE = "odys.slave_topk"
+MERGE_SCOPE = "odys.set_merge"
+
+
 class SearchResult(NamedTuple):
     docids: jnp.ndarray  # int32[Q, k] global docIDs, ascending (= rank order)
     n_hits: jnp.ndarray  # int32[Q]    total matches across all shards
@@ -73,6 +83,42 @@ class SearchResult(NamedTuple):
 def _local_index(stacked: ShardedIndex) -> InvertedIndex:
     """Inside shard_map each device sees a leading shard dim of 1."""
     return InvertedIndex(*(x[0] for x in stacked))
+
+
+def place_index(index, mesh: Mesh):
+    """Lay a stacked index (or delta) over ``mesh``'s ``data`` axis, shard
+    ``s`` on the axis's ``s``-th device.  From host leaves each device
+    receives only its own shard's rows."""
+    return jax.device_put(index, NamedSharding(mesh, P("data")))
+
+
+def _slave_topk(idx: ShardedIndex, qb: QueryBatch, dlt, *, axis: str,
+                ns: int, **engine) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """One slave's answer inside ``shard_map``: its local top-k as global
+    docIDs, and its hit count."""
+    with jax.named_scope(SLAVE_SCOPE):
+        shard = lax.axis_index(axis)
+        ldelta = None if dlt is None else local_delta(dlt)
+        docs, hits = query_topk(_local_index(idx), qb, delta=ldelta, **engine)
+        return local_to_global_docids(docs, shard, ns), hits
+
+
+def _set_merge(gdocs: jnp.ndarray, hits: jnp.ndarray, *, axis: str, ns: int,
+               merge: str, backend: str,
+               interpret: bool | None) -> SearchResult:
+    """The master merge of the slaves' answers, on every device."""
+    with jax.named_scope(MERGE_SCOPE):
+        if merge == "tournament":
+            merged = tournament_merge(
+                gdocs, axis, ns, backend=backend, interpret=interpret
+            )
+        elif merge == "allgather":
+            merged = allgather_merge(
+                gdocs, axis, backend=backend, interpret=interpret
+            )
+        else:
+            raise ValueError(merge)
+        return SearchResult(merged, lax.psum(hits, axis))
 
 
 def _row_topk(cands: jnp.ndarray, k: int, backend: str,
@@ -174,26 +220,12 @@ def distributed_query_topk(
         check_vma=False,
     )
     def run(idx: ShardedIndex, qb: QueryBatch, dlt) -> SearchResult:
-        shard = lax.axis_index(axis)
-        local = _local_index(idx)
-        ldelta = None if dlt is None else local_delta(dlt)
-        docs, hits = query_topk(
-            local, qb, delta=ldelta, k=k, window=window,
+        gdocs, hits = _slave_topk(
+            idx, qb, dlt, axis=axis, ns=ns, k=k, window=window,
             attr_strategy=attr_strategy, backend=backend, interpret=interpret,
         )
-        gdocs = local_to_global_docids(docs, shard, ns)
-        if merge == "tournament":
-            merged = tournament_merge(
-                gdocs, axis, ns, backend=backend, interpret=interpret
-            )
-        elif merge == "allgather":
-            merged = allgather_merge(
-                gdocs, axis, backend=backend, interpret=interpret
-            )
-        else:
-            raise ValueError(merge)
-        total_hits = lax.psum(hits, axis)
-        return SearchResult(merged, total_hits)
+        return _set_merge(gdocs, hits, axis=axis, ns=ns, merge=merge,
+                          backend=backend, interpret=interpret)
 
     return run(index, batch, delta)
 
@@ -241,14 +273,10 @@ def slave_topk_unmerged(
         check_vma=False,
     )
     def run(idx: ShardedIndex, qb: QueryBatch, dlt) -> SearchResult:
-        shard = lax.axis_index(axis)
-        local = _local_index(idx)
-        ldelta = None if dlt is None else local_delta(dlt)
-        docs, hits = query_topk(
-            local, qb, delta=ldelta, k=k, window=window,
+        gdocs, hits = _slave_topk(
+            idx, qb, dlt, axis=axis, ns=ns, k=k, window=window,
             attr_strategy=attr_strategy, backend=backend, interpret=interpret,
         )
-        gdocs = local_to_global_docids(docs, shard, ns)
         return SearchResult(gdocs[None], hits[None])
 
     return run(index, batch, delta)
@@ -300,26 +328,14 @@ def replicated_query_topk(
         check_vma=False,
     )
     def run(idx, qb: QueryBatch, dlt) -> SearchResult:
-        shard = lax.axis_index(axis)
-        local = _local_index(ShardedIndex(*(x[0] for x in idx)))
-        ldelta = (
-            None if dlt is None
-            else local_delta(ShardedDelta(*(x[0] for x in dlt)))
-        )
-        docs, hits = query_topk(
-            local, qb, delta=ldelta, k=k, window=window,
+        gdocs, hits = _slave_topk(
+            ShardedIndex(*(x[0] for x in idx)), qb,
+            None if dlt is None else ShardedDelta(*(x[0] for x in dlt)),
+            axis=axis, ns=ns, k=k, window=window,
             attr_strategy=attr_strategy, backend=backend, interpret=interpret,
         )
-        gdocs = local_to_global_docids(docs, shard, ns)
-        if merge == "tournament":
-            merged = tournament_merge(
-                gdocs, axis, ns, backend=backend, interpret=interpret
-            )
-        else:
-            merged = allgather_merge(
-                gdocs, axis, backend=backend, interpret=interpret
-            )
-        return SearchResult(merged, lax.psum(hits, axis))
+        return _set_merge(gdocs, hits, axis=axis, ns=ns, merge=merge,
+                          backend=backend, interpret=interpret)
 
     return run(_stack_for_pods(index), batch, pod_delta)
 

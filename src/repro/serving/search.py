@@ -35,18 +35,18 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from collections import defaultdict
 
 import numpy as np
 
 import jax
-
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.engine import make_query_batch
 from repro.core.index import INVALID_DOC, IndexMeta, ShardedIndex
 from repro.core.parallel import (
     SearchResult,
     distributed_query_topk,
+    place_index,
     replicated_query_topk,
 )
 from repro.data.corpus import Corpus
@@ -145,6 +145,9 @@ class SearchService:
         self.index = index
         self.meta = meta
         self.mesh = mesh
+        self.registry = registry if registry is not None else get_registry()
+        self._set_index: list[ShardedIndex] | None = None
+        self._observe_build()
         self._place_index()
         self.ns = ns
         self.k = k
@@ -178,7 +181,6 @@ class SearchService:
         if max(buckets) > t_max:
             raise ValueError(f"t_max_buckets {buckets} exceed t_max={t_max}")
         self.set_meshes = list(set_meshes) if set_meshes is not None else None
-        self._set_index: list[ShardedIndex] | None = None
         # delta placements per set slice; key None = the service mesh
         self._set_delta: dict[int | None, tuple[object, object]] = {}
         if self.set_meshes is not None:
@@ -198,7 +200,6 @@ class SearchService:
             from repro.serving.router import HealthAwareRouter
 
             router = HealthAwareRouter(n_sets, set_health)
-        self.registry = registry if registry is not None else get_registry()
         self._m_mutation = self.registry.histogram(
             "odys_mutation_apply_seconds",
             help="one insert/delete/update call on the writer, compaction "
@@ -273,6 +274,7 @@ class SearchService:
             writer, verify=verify,
             term_capacity=term_capacity, doc_headroom=doc_headroom,
         )
+        self._observe_build()
         self._place_index()
         if self.set_meshes is not None:
             # the main index changed identity: every slice re-places it
@@ -303,13 +305,24 @@ class SearchService:
         extra = 1 if (site is not None and self.strategy == "site_term") else 0
         return len(terms) + extra
 
+    def _observe_build(self) -> None:
+        """Each shard's host build time of the index at hand, when its
+        metadata carries them (``build_sharded_index``)."""
+        hist = self.registry.histogram(
+            "odys_index_shard_build_seconds",
+            help="host build of one shard of the main index (stripe, invert, "
+                 "lay out), shards built concurrently")
+        for seconds in self.meta.shard_build_s:
+            hist.observe(seconds)
+
     def _place_index(self) -> None:
         """Lay the main index out over the service mesh's ``data`` axis,
         one shard per device, once — not copied from the default device
-        at every dispatch."""
-        self.index = jax.device_put(
-            self.index, NamedSharding(self.mesh, P("data"))
-        )
+        at every dispatch.  From the build's host arrays each device
+        receives only its own shard.  Traced: ``odys.index_place``."""
+        with annotation("index_place"):
+            self.index = place_index(self.index, self.mesh)
+        self._export_device_bytes()
 
     def _place_set_indexes(self) -> None:
         """(Re)place the main index on every set's mesh slice.
@@ -318,11 +331,24 @@ class SearchService:
         the replication that makes sets independent failure/capacity
         domains (§3.1/§5.2).  Also drops the per-set delta placements:
         callers re-place lazily at the next dispatch."""
-        self._set_index = [
-            jax.device_put(self.index, NamedSharding(m, P("data")))
-            for m in self.set_meshes
-        ]
+        with annotation("index_place"):
+            self._set_index = [place_index(self.index, m) for m in self.set_meshes]
         self._set_delta.clear()
+        self._export_device_bytes()
+
+    def _export_device_bytes(self) -> None:
+        """``odys_index_device_bytes{device}``: the main index's bytes
+        resident on each device, over every placement of it."""
+        per_device: dict[int, int] = defaultdict(int)
+        for placed in [self.index, *(self._set_index or [])]:
+            for leaf in jax.tree.leaves(placed):
+                for shard in leaf.addressable_shards:
+                    per_device[shard.device.id] += shard.data.nbytes
+        for device, n in sorted(per_device.items()):
+            self.registry.gauge(
+                "odys_index_device_bytes", device=str(device),
+                help="main index bytes placed on the device",
+            ).set(n)
 
     def _delta_snapshot(
         self, set_id: int | None = None, clock: PhaseClock | None = None
@@ -353,7 +379,7 @@ class SearchService:
             clock.open("delta_place")
         snap = self.writer.device_delta()
         mesh = self.mesh if set_id is None else self.set_meshes[set_id]
-        placed = jax.device_put(snap, NamedSharding(mesh, P("data")))
+        placed = place_index(snap, mesh)
         if clock is not None:
             clock.close("delta_publish")
         self._set_delta[set_id] = (ver, placed)
